@@ -33,6 +33,7 @@ from benchmarks import (
     bench_serve,
     bench_table3,
 )
+from repro.launch.compile_cache import use_compile_cache
 
 BENCHES = {
     "table3": bench_table3.main,
@@ -102,6 +103,7 @@ def main() -> None:
             n for n in BENCHES if n != "autotune"
         ]
 
+    use_compile_cache()
     rows = []
     failed = []
     all_bench_lines = []

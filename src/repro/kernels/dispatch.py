@@ -13,9 +13,9 @@ Every ``PackedWeight.matmul`` lands here.  The dispatcher keys on
   dense-weight fallback path anywhere in dispatch.
 
 Shapes that don't divide the chosen tile are **zero-padded** to it (M up
-to the sublane, N up to the lane/tile, K never — K is always a common
-multiple of the 32-code plane word and the scale group, so an exact
-K tile always exists).  Zero x rows and zero plane words contribute
+to the sublane, N up to the lane/tile, K never — a K with no lane-aligned
+divisor is tiled whole, which the TPU lowering accepts as a full-array
+block dimension).  Zero x rows and zero plane words contribute
 exact zeros, so padding changes no output value; the pad is sliced off
 after the kernel.  This eliminates the old behaviour where a tile-ragged
 shape silently materialized the whole dense weight inside jit.
@@ -252,15 +252,19 @@ def _fit_dim(dim: int, pref: int, align: int) -> tuple[int, int]:
 
 
 def _fit_k(k: int, pref: int, g: int) -> int:
-    """K tile: largest divisor of K <= pref that the plane word (32) and the
-    scale group both divide.  Always exists — K is a common multiple of 32
-    and G, hence of lcm(32, G) — so K is never padded (padding K would also
-    mean fabricating scale rows)."""
-    mult = (PLANE * g) // math.gcd(PLANE, g)
-    for t in range(min(pref, k), 0, -1):
-        if k % t == 0 and t % mult == 0:
+    """K tile: the whole K, or the largest divisor of K <= pref that keeps
+    every K-tiled block Mosaic-legal.  A split tile is the last dim of the
+    x block (lane-aligned: a multiple of 128), and it sets the sublane dim
+    of the plane block (bk//32) and of the scale block (bk//G), each a
+    multiple of 8.  K is never padded (padding K would also mean
+    fabricating scale rows), so a K with no such divisor is one tile."""
+    if k <= pref:
+        return k
+    mult = math.lcm(LANE, PLANE * SUBLANE, g * SUBLANE)
+    for t in range(pref // mult * mult, 0, -mult):
+        if k % t == 0:
             return t
-    return mult  # mult divides k by construction
+    return k
 
 
 def plan(m: int, k: int, n: int, g: int, *, backend: str | None = None,

@@ -48,9 +48,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import MASK_VARIANTS
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 PLANE = 32  # codes per bit-plane word (matches codec.PLANE_GROUP)
 
 
@@ -249,7 +246,7 @@ def qsq_matmul_masked(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xs, planes, scales)
 
@@ -305,6 +302,6 @@ def qsq_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, planes, scales)

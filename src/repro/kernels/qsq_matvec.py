@@ -34,7 +34,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.qsq_matmul import (
-    _COMPILER_PARAMS,
     PLANE,
     _check_planes_shape,
     _decoder,
@@ -171,7 +170,7 @@ def qsq_matvec_masked(
         out_specs=pl.BlockSpec((m, bn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xs, planes, scales)
 
@@ -232,6 +231,6 @@ def qsq_matvec(
         out_specs=pl.BlockSpec((m, bn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, planes, scales)

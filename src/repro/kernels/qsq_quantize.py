@@ -21,9 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _qsq_quantize_kernel(w_ref, codes_ref, scales_ref, *, group_size: int, phi: int):
     bk, bn = w_ref.shape
@@ -82,6 +79,6 @@ def qsq_quantize(
             jax.ShapeDtypeStruct((k, n), jnp.int32),
             jax.ShapeDtypeStruct((k // group_size, n), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(w)

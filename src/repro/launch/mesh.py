@@ -14,12 +14,8 @@ import jax
 
 
 def _mesh(shape, axes):
-    # jax.sharding.AxisType only exists on newer jax; older versions default
-    # every axis to Auto, which is exactly what we want.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

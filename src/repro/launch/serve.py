@@ -33,9 +33,7 @@ keeps the longest agreeing prefix.  Tokens are identical to plain
 serving; the wins print per request as drafted/accepted counters and as
 the stream's acceptance rate and weight-bytes per accepted token.
 
-On a real pod the same entry point builds the production mesh and shards
-params/caches with launch/mesh.py rules (see launch/dryrun.py for the
-lowering path that proves those shardings compile).
+The launcher serves on one device; it builds no mesh.
 """
 from __future__ import annotations
 
@@ -47,6 +45,7 @@ import numpy as np
 
 from repro import api
 from repro.configs import ARCH_IDS, get_arch
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.api import Model
 from repro.models.base import init_params
 from repro.quant import tree_bits_report
@@ -155,6 +154,7 @@ def main():
             ap.error(f"--speculate window must be >= 1; got {k}")
         speculate = api.SpecConfig(draft, k)
 
+    use_compile_cache()
     cfg = get_arch(args.arch, smoke=args.smoke)
     model = Model(cfg)
     params = init_params(jax.random.PRNGKey(0), model.param_descs())

@@ -68,6 +68,39 @@ def test_plan_never_pads_k():
         assert p.k % p.bk == 0
 
 
+def _mosaic_legal_k_tile(p, g):
+    """A K tile is the whole K, or lane-aligned with sublane-aligned plane
+    (bk//32) and scale (bk//G) blocks — the TPU lowering's (8, 128) rule."""
+    return p.bk == p.k or (p.k % p.bk == 0 and p.bk % dispatch.LANE == 0
+                           and (p.bk // codec.PLANE_GROUP) % dispatch.SUBLANE == 0
+                           and (p.bk // g) % dispatch.SUBLANE == 0)
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("m", [4, 64])
+@pytest.mark.parametrize("g", [16, 32, 64])
+@pytest.mark.parametrize("k", [576, 1536, 4096, 11008, 17408])
+def test_plan_k_tile_is_whole_or_lane_aligned(k, g, m, backend):
+    p = dispatch.plan(m, k, 4096, g, backend=backend)
+    assert _mosaic_legal_k_tile(p, g), p
+    assert p.bk == p.k or p.bk % 128 == 0
+
+
+def test_plan_keeps_tuned_tiles_mosaic_legal():
+    """A tuned-table entry cannot produce an illegal block: ragged
+    preferences are refitted to lane/sublane-aligned tiles."""
+    dispatch.set_tuned_table({"tpu": {
+        "gemv": {"kind": "gemv", "bm": 8, "bk": 288, "bn": 200},
+        "gemm": {"kind": "gemm", "bm": 12, "bk": 1376, "bn": 300},
+    }})
+    for m, k, n in [(4, 1536, 576), (8, 11008, 4096), (64, 11008, 4096),
+                    (64, 1536, 49152)]:
+        p = dispatch.plan(m, k, n, 16, backend="tpu")
+        assert _mosaic_legal_k_tile(p, 16), p
+        assert p.bn == p.pn or p.bn % dispatch.LANE == 0, p
+        assert p.bm == p.pm or p.bm % dispatch.SUBLANE == 0, p
+
+
 def test_use_kernel_false_routes_to_xla_ref():
     assert dispatch.plan(8, 1024, 256, 64, use_kernel=False).route == \
         dispatch.ROUTE_XLA

@@ -70,9 +70,13 @@ def test_decode_matches_forward(arch_id):
         ck, cv = encdec_prefill_cross(params, cfg, batch["frames"])
         cache = EncDecCache(kv=cache.kv, cross_k=ck, cross_v=cv)
 
+    # one compiled decode for every step: an eager call compiles a fresh
+    # layer scan each time, and a worker that keeps thousands of such
+    # programs runs out of memory mappings (vm.max_map_count)
+    decode = jax.jit(model.decode)
     outs = []
     for t in range(s):
-        logits, cache = model.decode(
+        logits, cache = decode(
             params, cache, {"tokens": batch["tokens"][:, t : t + 1]}
         )
         outs.append(logits[:, 0])
@@ -90,11 +94,13 @@ def test_swa_ring_buffer_decode():
     key = jax.random.PRNGKey(2)
     params = init_params(key, model.param_descs())
 
+    decode = jax.jit(model.decode)  # see test_decode_matches_forward
+
     def run(prefix_tokens):
         cache = init_params(key, model.cache_descs(1, 120))
         logits = None
         for t in prefix_tokens:
-            logits, cache = model.decode(
+            logits, cache = decode(
                 params, cache, {"tokens": jnp.array([[t]], jnp.int32)}
             )
         return logits

@@ -271,7 +271,14 @@ def _moe_model():
 def test_moe_dead_lane_out_of_expert_competition():
     """layers.moe with an active mask: a dead lane's token must not change
     live lanes' outputs (it used to claim capacity slots like a live batch
-    mate), and its own output must be zero."""
+    mate), and its own output must be zero.
+
+    The dead lane 0 holds a copy of live lane 1's token, so it routes to
+    the same top-k experts, and capacity is one token per expert
+    (ceil(4 tokens * 2 / 4 experts * 0.3) = 1).  Routing ranks
+    assignments in lane order, so an unmasked copy takes both of lane 1's
+    capacity slots and lane 1 is dropped: competition is guaranteed by
+    construction, not by the random init."""
     from repro.models import layers as L
 
     key = jax.random.PRNGKey(0)
@@ -279,20 +286,23 @@ def test_moe_dead_lane_out_of_expert_competition():
     p = init_params(key, L.moe_descs(d, ff, e))
     rng = np.random.RandomState(0)
     x1 = jnp.asarray(rng.randn(4, 1, d), jnp.float32)
-    x2 = x1.at[1].set(jnp.asarray(rng.randn(1, d), jnp.float32))
-    active = jnp.asarray([1, 0, 1, 1], jnp.int32)
-    live = np.array([0, 2, 3])
+    x1 = x1.at[0].set(x1[1])  # dead lane = copy of a live token
+    x2 = x1.at[0].set(jnp.asarray(rng.randn(1, d), jnp.float32))
+    active = jnp.asarray([0, 1, 1, 1], jnp.int32)
+    live = np.array([1, 2, 3])
     y1 = np.asarray(L.moe(p, x1, top_k=2, capacity_factor=0.3,
                           active=active)[0])
     y2 = np.asarray(L.moe(p, x2, top_k=2, capacity_factor=0.3,
                           active=active)[0])
     np.testing.assert_array_equal(y1[live], y2[live])
-    assert (y1[1] == 0).all()
-    # without the mask the dead token DOES perturb live lanes (the bug the
-    # mask fixes) — guards against the test going vacuous
+    assert (y1[0] == 0).all()
+    # masked: lane 1 is first in line for its experts and is served
+    assert (y1[1] != 0).any()
+    # unmasked: the copy claims lane 1's capacity and lane 1 is dropped —
+    # the competition the mask removes
     z1 = np.asarray(L.moe(p, x1, top_k=2, capacity_factor=0.3)[0])
-    z2 = np.asarray(L.moe(p, x2, top_k=2, capacity_factor=0.3)[0])
-    assert not np.array_equal(z1[live], z2[live])
+    assert (z1[1] == 0).all()
+    assert not np.array_equal(z1[live], y1[live])
 
 
 def test_moe_slot_history_invariance():

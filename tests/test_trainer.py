@@ -140,9 +140,8 @@ def test_elastic_restore_different_sharding(tmp_path):
     path = tmp_path / "elastic.npz"
     save_pytree(tree, path)
 
-    kw = ({"axis_types": (jax.sharding.AxisType.Auto,)}
-          if hasattr(jax.sharding, "AxisType") else {})
-    mesh = jax.make_mesh((1,), ("data",), **kw)
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     shardings = {"w": NamedSharding(mesh, P("data", None))}
     restored = load_pytree(tree, path, sharding=shardings)
     np.testing.assert_array_equal(np.asarray(restored["w"]), np.asarray(tree["w"]))
