@@ -36,7 +36,6 @@ path a shape took.
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import json
 import math
@@ -81,35 +80,9 @@ counters: collections.Counter = collections.Counter()
 traffic: collections.Counter = collections.Counter()
 
 
-# serving-phase label for traffic attribution ("" = unlabeled).  Set only
-# via dispatch_phase(); counters/traffic stay private to this module.
-_phase: str = ""
-
-
 def reset_counters() -> None:
     counters.clear()
     traffic.clear()
-
-
-@contextlib.contextmanager
-def dispatch_phase(label: str):
-    """Attribute plane traffic traced inside the block to a serving phase.
-
-    The serving engine wraps its speculative draft ticks and verify
-    dispatches in ``dispatch_phase("draft")`` / ``dispatch_phase("verify")``
-    so :data:`traffic` splits plane reads by phase under extra
-    ``"phase:<label>:plane_words_read|full"`` keys.  Like every counter
-    here these move at TRACE time only — they record what each compiled
-    program streams per call, labeled by the phase that first compiled
-    it — so cached dispatches (and ``no_retrace`` blocks) never see them
-    drift."""
-    global _phase
-    prev = _phase
-    _phase = str(label)
-    try:
-        yield
-    finally:
-        _phase = prev
 
 
 @dataclasses.dataclass(frozen=True)
@@ -314,9 +287,6 @@ def _count_traffic(p: Plan, k: int, n_read: int) -> None:
     traffic["plane_reads"] += n_read * tiles
     traffic["plane_words_read"] += n_read * words
     traffic["plane_words_full"] += 3 * words
-    if _phase:
-        traffic[f"phase:{_phase}:plane_words_read"] += n_read * words
-        traffic[f"phase:{_phase}:plane_words_full"] += 3 * words
 
 
 def packed_matmul(

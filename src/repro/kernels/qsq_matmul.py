@@ -248,6 +248,7 @@ def qsq_matmul_masked(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="qsq_matmul_masked",
     )(xs, planes, scales)
 
 
@@ -304,4 +305,5 @@ def qsq_matmul(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="qsq_matmul",
     )(x, planes, scales)

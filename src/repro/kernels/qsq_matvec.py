@@ -172,6 +172,7 @@ def qsq_matvec_masked(
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="qsq_matvec_masked",
     )(xs, planes, scales)
 
 
@@ -233,4 +234,5 @@ def qsq_matvec(
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="qsq_matvec",
     )(x, planes, scales)

@@ -81,4 +81,5 @@ def qsq_quantize(
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="qsq_quantize",
     )(w)

@@ -78,8 +78,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
-from repro.kernels import dispatch
 from repro.models.api import Model
 from repro.models.base import init_params
 from repro.serve.admission import ADMIT, REJECT, SHED, AdmissionPolicy, LoadView
@@ -590,8 +590,21 @@ class ServeEngine:
         batch reads a fraction of the weight bytes.  Demand is a static
         jit argument; at most one retrace per distinct tier.  The stream
         cost clock (:attr:`now`) advances by the step's summed dispatch
-        read fractions — cheaper tiers genuinely buy back clock time."""
+        read fractions — cheaper tiers genuinely buy back clock time.
+
+        Each step records host spans on the profiler's clock, which cost
+        about a microsecond each and record nothing unless a profiler
+        session runs: ``serve.step`` around it, then ``serve.admit`` per
+        admission, ``serve.decode`` (or ``serve.draft`` /
+        ``serve.verify`` for a speculative round), each with
+        ``.dispatch`` and ``.sync`` children; every wait for the device
+        is a span whose name ends in ``.sync``."""
         s = self._ensure_session()
+        with StepTraceAnnotation("serve.step", step_num=s.step_idx,
+                                 queued=len(s.sched.queue)):
+            return self._step(s)
+
+    def _step(self, s: _Session) -> StepInfo:
         admitted: list[int] = []
         finished: list[int] = []
         timed_out: list[int] = []
@@ -604,34 +617,40 @@ class ServeEngine:
             s.active[slot] = 0  # dead lane: a data change, never a retrace
             timed_out.append(req.rid)
         for slot, req in s.sched.admissible():
-            s.sched.activate(slot, req, s.step_idx, now=s.now)
-            s.tiers[slot] = self._tier_index(req.quality)
-            admitted.append(req.rid)
-            toks = np.zeros((1, s.prefill_len), np.int32)
-            toks[0, s.prefill_len - len(req.tokens):] = req.tokens
-            # one dispatch: prefill + lane insert + on-device argmax; the
-            # host syncs on a single int32, not a (vocab,) logits row.
-            # The prefill runs at the REQUEST's tier (per-row plane masks)
-            # and streams only the planes that tier demands.
-            demand = int(s.tiers[slot])
-            s.cache, first = self._admit(
-                self.params, s.zero_slot_cache, s.cache, jnp.asarray(toks),
-                jnp.asarray([len(req.tokens)], jnp.int32), jnp.int32(slot),
-                jnp.asarray(s.tiers[slot:slot + 1]), demand,
-            )
-            r, f = self._forward_plane_words(demand)
-            s.plane_words_read += r
-            s.plane_words_full += f
-            s.tokens_emitted += 1
-            cost += self._dispatch_cost(demand)
-            first = int(first)
-            s.sched.start_decoding(slot)
-            s.cur[slot, 0] = first
-            if s.sched.record(slot, first, s.step_idx, now=s.now):
-                s.sched.evict(slot)  # max_new == 1: done at admission
-                finished.append(req.rid)
-            else:
-                s.active[slot] = 1
+            demand = self._tier_index(req.quality)
+            with TraceAnnotation("serve.admit", rid=req.rid, slot=slot,
+                                 tier=demand, prompt_len=len(req.tokens)):
+                s.sched.activate(slot, req, s.step_idx, now=s.now)
+                s.tiers[slot] = demand
+                admitted.append(req.rid)
+                toks = np.zeros((1, s.prefill_len), np.int32)
+                toks[0, s.prefill_len - len(req.tokens):] = req.tokens
+                # one dispatch: prefill + lane insert + on-device argmax;
+                # the host syncs on a single int32, not a (vocab,) logits
+                # row.  The prefill runs at the REQUEST's tier (per-row
+                # plane masks) and streams only the planes it demands.
+                with TraceAnnotation("serve.admit.dispatch"):
+                    s.cache, first = self._admit(
+                        self.params, s.zero_slot_cache, s.cache,
+                        jnp.asarray(toks),
+                        jnp.asarray([len(req.tokens)], jnp.int32),
+                        jnp.int32(slot), jnp.asarray(s.tiers[slot:slot + 1]),
+                        demand,
+                    )
+                r, f = self._forward_plane_words(demand)
+                s.plane_words_read += r
+                s.plane_words_full += f
+                s.tokens_emitted += 1
+                cost += self._dispatch_cost(demand)
+                with TraceAnnotation("serve.admit.sync"):
+                    first = int(first)
+                s.sched.start_decoding(slot)
+                s.cur[slot, 0] = first
+                if s.sched.record(slot, first, s.step_idx, now=s.now):
+                    s.sched.evict(slot)  # max_new == 1: done at admission
+                    finished.append(req.rid)
+                else:
+                    s.active[slot] = 1
         live = s.sched.decoding_slots()
         demand_used: int | None = None
         drafted_n = accepted_n = 0
@@ -657,24 +676,29 @@ class ServeEngine:
         elif live:
             demand = plane_demand(s.tiers[slot] for slot in live)
             demand_used = demand
-            nxt, s.cache = self._cont_step(
-                self.params, s.cache, jnp.asarray(s.cur),
-                jnp.asarray(s.active), jnp.asarray(s.tiers), demand,
-            )
-            r, f = self._forward_plane_words(demand)
-            s.plane_words_read += r
-            s.plane_words_full += f
-            s.tokens_emitted += len(live)
-            cost += self._dispatch_cost(demand)
-            nxt = np.asarray(nxt)  # the step's one host sync
-            for slot in live:
-                s.cur[slot, 0] = nxt[slot]
-                rid = s.sched.slot_req[slot].rid
-                if s.sched.record(slot, int(nxt[slot]), s.step_idx,
-                                  now=s.now):
-                    s.sched.evict(slot)
-                    s.active[slot] = 0
-                    finished.append(rid)
+            with TraceAnnotation("serve.decode", live=len(live),
+                                 demand=demand):
+                with TraceAnnotation("serve.decode.dispatch"):
+                    nxt, s.cache = self._cont_step(
+                        self.params, s.cache, jnp.asarray(s.cur),
+                        jnp.asarray(s.active), jnp.asarray(s.tiers), demand,
+                    )
+                r, f = self._forward_plane_words(demand)
+                s.plane_words_read += r
+                s.plane_words_full += f
+                s.tokens_emitted += len(live)
+                cost += self._dispatch_cost(demand)
+                with TraceAnnotation("serve.decode.sync"):
+                    nxt = np.asarray(nxt)  # the decode's one host sync
+                with TraceAnnotation("serve.decode.record"):
+                    for slot in live:
+                        s.cur[slot, 0] = nxt[slot]
+                        rid = s.sched.slot_req[slot].rid
+                        if s.sched.record(slot, int(nxt[slot]), s.step_idx,
+                                          now=s.now):
+                            s.sched.evict(slot)
+                            s.active[slot] = 0
+                            finished.append(rid)
         s.step_idx += 1
         s.now += cost
         return StepInfo(admitted=tuple(admitted), finished=tuple(finished),
@@ -732,71 +756,77 @@ class ServeEngine:
                 break  # every non-spec lane finished and k_effs exhausted
             demand = plane_demand(int(draft_tiers[slot])
                                   for slot in live_now)
-            with dispatch.dispatch_phase("draft"):
-                nxt, s.cache = self._cont_step(
-                    self.params, s.cache, jnp.asarray(s.cur),
-                    jnp.asarray(draft_active), jnp.asarray(draft_tiers),
-                    demand,
+            with TraceAnnotation("serve.draft", live=len(live_now),
+                                 demand=demand):
+                with TraceAnnotation("serve.draft.dispatch"):
+                    nxt, s.cache = self._cont_step(
+                        self.params, s.cache, jnp.asarray(s.cur),
+                        jnp.asarray(draft_active), jnp.asarray(draft_tiers),
+                        demand,
+                    )
+                r, f = self._forward_plane_words(demand)
+                s.plane_words_read += r
+                s.plane_words_full += f
+                cost += self._dispatch_cost(demand)
+                with TraceAnnotation("serve.draft.sync"):
+                    nxt = np.asarray(nxt)
+                for slot in live_now:
+                    s.cur[slot, 0] = int(nxt[slot])
+                    if slot in spec:
+                        drafts[slot].append(int(nxt[slot]))  # proposed only
+                    else:
+                        s.tokens_emitted += 1
+                        rid = s.sched.slot_req[slot].rid
+                        if s.sched.record(slot, int(nxt[slot]), s.step_idx,
+                                          now=s.now):
+                            s.sched.evict(slot)
+                            s.active[slot] = 0
+                            finished.append(rid)
+        vdemand = plane_demand(int(s.tiers[slot]) for slot in spec)
+        with TraceAnnotation("serve.verify", lanes=len(spec), demand=vdemand):
+            w = k_round + 1
+            window = np.zeros((s.sched.n_slots, w), np.int32)
+            wlen = np.zeros((s.sched.n_slots,), np.int32)
+            smask = np.zeros((s.sched.n_slots,), np.int32)
+            starts = np.zeros((s.sched.n_slots,), np.int32)
+            for slot, (k_eff, _) in spec.items():
+                window[slot, 0] = anchor[slot]
+                window[slot, 1:1 + k_eff] = drafts[slot]
+                wlen[slot] = k_eff + 1
+                smask[slot] = 1
+                starts[slot] = start[slot]
+            with TraceAnnotation("serve.verify.dispatch"):
+                toks, acc, s.cache = self._verify(
+                    self.params, s.cache, jnp.asarray(window),
+                    jnp.asarray(starts), jnp.asarray(wlen),
+                    jnp.asarray(smask), jnp.asarray(s.tiers), vdemand,
                 )
-            r, f = self._forward_plane_words(demand)
+            r, f = self._forward_plane_words(vdemand)
             s.plane_words_read += r
             s.plane_words_full += f
-            cost += self._dispatch_cost(demand)
-            nxt = np.asarray(nxt)
-            for slot in live_now:
-                s.cur[slot, 0] = int(nxt[slot])
-                if slot in spec:
-                    drafts[slot].append(int(nxt[slot]))  # proposed, not emitted
-                else:
-                    s.tokens_emitted += 1
-                    rid = s.sched.slot_req[slot].rid
-                    if s.sched.record(slot, int(nxt[slot]), s.step_idx,
-                                      now=s.now):
-                        s.sched.evict(slot)
-                        s.active[slot] = 0
-                        finished.append(rid)
-        w = k_round + 1
-        window = np.zeros((s.sched.n_slots, w), np.int32)
-        wlen = np.zeros((s.sched.n_slots,), np.int32)
-        smask = np.zeros((s.sched.n_slots,), np.int32)
-        starts = np.zeros((s.sched.n_slots,), np.int32)
-        for slot, (k_eff, _) in spec.items():
-            window[slot, 0] = anchor[slot]
-            window[slot, 1:1 + k_eff] = drafts[slot]
-            wlen[slot] = k_eff + 1
-            smask[slot] = 1
-            starts[slot] = start[slot]
-        vdemand = plane_demand(int(s.tiers[slot]) for slot in spec)
-        with dispatch.dispatch_phase("verify"):
-            toks, acc, s.cache = self._verify(
-                self.params, s.cache, jnp.asarray(window),
-                jnp.asarray(starts), jnp.asarray(wlen), jnp.asarray(smask),
-                jnp.asarray(s.tiers), vdemand,
-            )
-        r, f = self._forward_plane_words(vdemand)
-        s.plane_words_read += r
-        s.plane_words_full += f
-        cost += self._dispatch_cost(vdemand)
-        toks = np.asarray(toks)
-        acc = np.asarray(acc)  # the round's final host sync
-        drafted_n = accepted_n = 0
-        for slot, (k_eff, _) in spec.items():
-            a = int(acc[slot])
-            req = s.sched.slot_req[slot]
-            req.drafted += k_eff
-            req.accepted += a
-            drafted_n += k_eff
-            accepted_n += a
-            s.cur[slot, 0] = int(toks[slot, a])  # bonus token: the new cur
-            s.tokens_emitted += a + 1
-            rid = req.rid
-            done = False
-            for tok in toks[slot, :a + 1]:
-                done = s.sched.record(slot, int(tok), s.step_idx, now=s.now)
-            if done:  # a+1 <= remaining, so only the last token can finish
-                s.sched.evict(slot)
-                s.active[slot] = 0
-                finished.append(rid)
+            cost += self._dispatch_cost(vdemand)
+            with TraceAnnotation("serve.verify.sync"):
+                toks = np.asarray(toks)
+                acc = np.asarray(acc)  # the round's final host sync
+            drafted_n = accepted_n = 0
+            for slot, (k_eff, _) in spec.items():
+                a = int(acc[slot])
+                req = s.sched.slot_req[slot]
+                req.drafted += k_eff
+                req.accepted += a
+                drafted_n += k_eff
+                accepted_n += a
+                s.cur[slot, 0] = int(toks[slot, a])  # bonus token: new cur
+                s.tokens_emitted += a + 1
+                rid = req.rid
+                done = False
+                for tok in toks[slot, :a + 1]:
+                    done = s.sched.record(slot, int(tok), s.step_idx,
+                                          now=s.now)
+                if done:  # a+1 <= remaining: only the last token can finish
+                    s.sched.evict(slot)
+                    s.active[slot] = 0
+                    finished.append(rid)
         s.drafted += drafted_n
         s.accepted += accepted_n
         return vdemand, cost, drafted_n, accepted_n

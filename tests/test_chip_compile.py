@@ -6,13 +6,16 @@ block that breaks the (8, 128) tiling rule or overflows VMEM.  Each case
 plans its tiles with ``dispatch.plan(..., backend="tpu")`` exactly as the
 served path does on the chip, lowers the Pallas kernel with
 ``interpret=False`` on abstract shapes, and checks that the compiled
-program holds the kernel (``tpu_custom_call``).
+program holds the kernel (``tpu_custom_call``) under the stable name its
+``pallas_call`` gives it, the name the chip benchmark's trace reduction
+matches.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,8 +86,17 @@ def _compile_for_chip(sharding, m, k, n, *, masked, demand_drop=0):
         fn = ops.qsq_matvec if gemv else ops.qsq_matmul
     compiled = jax.jit(lambda a, b, c: fn(a, b, c, **kw)).lower(
         x, planes, scales).compile()
-    assert "tpu_custom_call" in compiled.as_text(), (m, k, n, p)
+    want = ("qsq_matvec" if gemv else "qsq_matmul") + ("_masked" if masked else "")
+    assert _kernel_names(compiled.as_text()) == [want], (m, k, n, p)
     return p
+
+
+def _kernel_names(hlo: str) -> list[str]:
+    """The HLO instruction names of the program's Pallas kernels, without
+    their numeric suffix: what the chip benchmark's trace reduction
+    matches (``%qsq_matvec_masked.43 = ... custom-call(...)``)."""
+    return sorted({m.group(1) for m in re.finditer(
+        r"%([\w.-]+?)\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)})
 
 
 @pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
@@ -109,3 +121,10 @@ def test_deepseek_wd_gemv_compiles_for_v5e(one_chip):
 def test_demand_drop_compiles_for_v5e(one_chip, m):
     # lo-tier demand: only the sign plane streams
     _compile_for_chip(one_chip, m, 576, 1536, masked=True, demand_drop=2)
+
+
+def test_quantize_kernel_name_for_v5e(one_chip):
+    w = jax.ShapeDtypeStruct((256, 256), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda a: ops.qsq_quantize(
+        a, group_size=G, interpret=False)).lower(w).compile()
+    assert _kernel_names(compiled.as_text()) == ["qsq_quantize"]

@@ -26,7 +26,6 @@ import pytest
 
 from repro import api
 from repro.configs.base import ArchConfig
-from repro.kernels import dispatch
 from repro.models.api import Model
 from repro.models.base import init_params
 from repro.quant.artifact import QualitySpec, QualityTier
@@ -207,20 +206,32 @@ def test_spec_cost_clock_charges_verify_as_one_tick(spec_artifact):
     assert eng.poll(rid).n_tokens == 8
 
 
-def test_spec_phase_labeled_traffic(spec_artifact):
-    """A freshly traced speculative stream attributes plane words to the
-    draft and verify phases in dispatch.traffic (trace-time accounting,
-    like every dispatch counter)."""
+def test_spec_round_spans(spec_artifact, host_spans):
+    """A speculative round of k drafts records k ``serve.draft`` spans and
+    one ``serve.verify``, each with one ``.dispatch`` and one ``.sync``
+    child, inside the step's ``serve.step`` — and no plain decode."""
     art = spec_artifact
-    dispatch.reset_counters()
     eng = art.engine(quality="hi", batch_slots=1, max_prompt=8, max_len=32)
-    eng.submit([1, 2, 3], max_new=6, speculate=SpecConfig("lo", k=2))
-    eng.run_until_drained()
-    assert dispatch.traffic["phase:draft:plane_words_read"] > 0
-    assert dispatch.traffic["phase:verify:plane_words_read"] > 0
-    # the draft program streams fewer words than its full-plane footprint
-    assert (dispatch.traffic["phase:draft:plane_words_read"]
-            < dispatch.traffic["phase:draft:plane_words_full"])
+    eng.submit([1, 2, 3], max_new=8, speculate=SpecConfig("lo", k=3))
+    with host_spans() as spans:
+        info = eng.step()
+    assert info.drafted == 3
+    names = [sp[0] for sp in spans]
+    assert names.count("serve.step") == 1
+    assert names.count("serve.admit") == 1
+    assert names.count("serve.draft") == 3
+    assert names.count("serve.verify") == 1
+    assert not any(n.startswith("serve.decode") for n in names)
+    step = spans[names.index("serve.step")]
+    for parent in (sp for sp in spans if sp[0] in ("serve.draft",
+                                                   "serve.verify")):
+        assert step[1] <= parent[1] and parent[2] <= step[2]
+        kids = [sp[0] for sp in spans if sp[0].startswith(parent[0] + ".")
+                and parent[1] <= sp[1] and sp[2] <= parent[2]]
+        assert kids == [parent[0] + ".dispatch", parent[0] + ".sync"]
+    lo = eng.tier_names.index("lo")
+    assert [sp[3]["demand"] for sp in spans if sp[0] == "serve.draft"] \
+        == [lo] * 3
 
 
 def test_spec_submit_validation(spec_artifact):
