@@ -47,7 +47,6 @@ import jax.numpy as jnp
 
 from repro.core import codec
 from repro.kernels import ref
-from repro.kernels.ref import MASK_VARIANTS
 
 PLANE = codec.PLANE_GROUP
 
@@ -76,7 +75,11 @@ counters: collections.Counter = collections.Counter()
 #   "plane_reads"         — plane-tiles streamed (planes touched x tiles)
 #   "plane_words_read"    — int32 plane words the routed kernel streams
 #   "plane_words_full"    — words a full 3-plane stream would have read
-# read/full < 1 is exactly the demand-driven HBM saving on that trace.
+#   "mask_variants"       — mask variants a masked call unrolls
+#   "mask_variants_suffix" — what MASK_VARIANTS[demand_drop:] would unroll
+# read/full < 1 is exactly the demand-driven HBM saving on that trace;
+# mask_variants/mask_variants_suffix < 1 is how often the leaf's tier plan
+# prunes a variant that the demand floor alone keeps.
 traffic: collections.Counter = collections.Counter()
 
 
@@ -301,6 +304,7 @@ def packed_matmul(
     sign_mag: bool = False,
     plane_major: bool = False,
     demand_drop: int = 0,
+    variants: tuple[int, ...] | None = None,
 ) -> jax.Array:
     """x (M,K) @ decode(planes (K//32,3,N), scales (K//G,N)) -> (M,N) f32.
 
@@ -310,7 +314,7 @@ def packed_matmul(
     dense weight.
 
     ``plane_mask`` (M,) int32 — one 3-bit code mask per x row, values from
-    :data:`MASK_VARIANTS` — makes the matmul quality-tiered PER ROW: row m
+    :data:`ref.MASK_VARIANTS` — makes the matmul quality-tiered PER ROW: row m
     contracts against the weight decoded under its own mask, bit-identical
     to the unmasked matmul on ``truncate(drop_m)`` planes.  The mask is a
     traced operand split into a fixed variant activation stack, so a
@@ -321,10 +325,13 @@ def packed_matmul(
     ``plane_major`` marks ``planes`` as (3, K//32, N) MSB-first, the layout
     whose HBM read shortens with demand; ``demand_drop`` (static, 0..2) is
     the batch demand floor: every live row drops at least that many planes,
-    so the kernel only streams/decodes the ``3 - demand_drop`` demanded
-    planes (plane-major) and variants ``MASK_VARIANTS[demand_drop:]``.
-    Rows whose mask demands a pruned variant contribute zeros; the caller
-    (engine demand vector) guarantees no live row does."""
+    so the kernel only streams the ``3 - demand_drop`` demanded planes
+    (plane-major).  ``variants`` (static) is the masked call's variant
+    set: an ordered subset of ``MASK_VARIANTS[demand_drop:]`` (the default)
+    holding the masks some live row can select
+    (``PackedWeight.mask_variants``).  Rows whose mask is not in it
+    contribute zeros; the caller (engine demand vector and the leaf's tier
+    plan) guarantees no live row has such a mask."""
     m, k = x.shape
     n = planes.shape[-1]
     if not 0 <= demand_drop < 3:
@@ -339,18 +346,22 @@ def packed_matmul(
     _count_traffic(p, k, n_read)
     if plane_mask is not None:
         counters[f"{p.route}:masked"] += 1
-        # variant split: xs[i] keeps exactly the rows masked
-        # MASK_VARIANTS[demand_drop + i] (a row matches one variant; others
-        # contribute exact zeros).  Pad rows carry mask 0 -> no variant ->
-        # exact zero rows, as before.
-        sel = jnp.stack([plane_mask == v for v in MASK_VARIANTS[demand_drop:]])
+        variants = ref.mask_variants(demand_drop, variants)
+        traffic["mask_variants"] += len(variants)
+        traffic["mask_variants_suffix"] += 3 - demand_drop
+        # variant split: xs[i] keeps exactly the rows masked variants[i]
+        # (a row matches one variant; others contribute exact zeros).  Pad
+        # rows carry mask 0 and rows outside the set match no variant ->
+        # exact zero rows.
+        sel = jnp.stack([plane_mask == v for v in variants])
         xs = jnp.where(sel[:, :, None], x[None], 0).astype(x.dtype)
 
     if p.route == ROUTE_XLA:
         if plane_mask is not None:
             return ref.qsq_matmul_masked_ref(
                 xs, planes, scales, group_size, sign_mag=sign_mag,
-                plane_major=plane_major, demand_drop=demand_drop)
+                plane_major=plane_major, demand_drop=demand_drop,
+                variants=variants)
         return ref.qsq_matmul_ref(
             x, planes, scales, group_size, sign_mag=sign_mag,
             plane_major=plane_major, n_planes=3 - demand_drop)
@@ -366,14 +377,16 @@ def packed_matmul(
                                         bk=p.bk, bn=p.bn, interpret=interpret,
                                         sign_mag=sign_mag,
                                         plane_major=plane_major,
-                                        demand_drop=demand_drop)
+                                        demand_drop=demand_drop,
+                                        variants=variants)
         else:
             out = ops.qsq_matmul_masked(xsp, pp, sp, group_size=group_size,
                                         bm=p.bm, bk=p.bk, bn=p.bn,
                                         interpret=interpret,
                                         sign_mag=sign_mag,
                                         plane_major=plane_major,
-                                        demand_drop=demand_drop)
+                                        demand_drop=demand_drop,
+                                        variants=variants)
         return out[:m, :n] if p.padded else out
 
     xp = _pad_axis(x, 0, p.pm)

@@ -93,20 +93,22 @@ def qsq_matmul_masked(
     sign_mag: bool = False,
     plane_major: bool = False,
     demand_drop: int = 0,
+    variants: tuple[int, ...] | None = None,
 ) -> jax.Array:
-    """Per-row plane-masked GEMM: xs (3 - demand_drop, M, K) variant-split
-    activations."""
+    """Per-row plane-masked GEMM: xs (len(variants), M, K) activations
+    split by mask variant (``ref.mask_variants``)."""
     if not use_pallas:
         return ref.qsq_matmul_masked_ref(xs, planes, scales, group_size,
                                          sign_mag=sign_mag,
                                          plane_major=plane_major,
-                                         demand_drop=demand_drop)
+                                         demand_drop=demand_drop,
+                                         variants=variants)
     if interpret is None:
         interpret = auto_interpret()
     return _qsq_matmul_masked_pallas(
         xs, planes, scales, group_size=group_size, bm=bm, bk=bk, bn=bn,
         interpret=interpret, sign_mag=sign_mag, plane_major=plane_major,
-        demand_drop=demand_drop,
+        demand_drop=demand_drop, variants=variants,
     )
 
 
@@ -123,20 +125,22 @@ def qsq_matvec_masked(
     sign_mag: bool = False,
     plane_major: bool = False,
     demand_drop: int = 0,
+    variants: tuple[int, ...] | None = None,
 ) -> jax.Array:
-    """Per-row plane-masked GEMV: xs (3 - demand_drop, M, K) variant-split
-    activations."""
+    """Per-row plane-masked GEMV: xs (len(variants), M, K) activations
+    split by mask variant (``ref.mask_variants``)."""
     if not use_pallas:
         return ref.qsq_matmul_masked_ref(xs, planes, scales, group_size,
                                          sign_mag=sign_mag,
                                          plane_major=plane_major,
-                                         demand_drop=demand_drop)
+                                         demand_drop=demand_drop,
+                                         variants=variants)
     if interpret is None:
         interpret = auto_interpret()
     return _qsq_matvec_masked_pallas(
         xs, planes, scales, group_size=group_size, bk=bk, bn=bn,
         interpret=interpret, sign_mag=sign_mag, plane_major=plane_major,
-        demand_drop=demand_drop,
+        demand_drop=demand_drop, variants=variants,
     )
 
 

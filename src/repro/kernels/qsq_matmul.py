@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import MASK_VARIANTS
+from repro.kernels.ref import MASK_VARIANTS, mask_variants
 
 PLANE = 32  # codes per bit-plane word (matches codec.PLANE_GROUP)
 
@@ -81,41 +81,30 @@ def _decoder(sign_mag: bool):
     return _decode_codes_sm if sign_mag else _decode_codes
 
 
-def _unpack_planes(planes_blk: jax.Array, bk: int, bn: int) -> jax.Array:
-    """(bk//32, 3, bn) int32 interleaved bit-planes -> (bk, bn) int32 codes."""
-    g = bk // PLANE
+def _code_bit(planes_blk: jax.Array, bit: int, bn: int,
+              plane_major: bool) -> jax.Array:
+    """(bk, bn) int32 0/1: code bit ``bit`` of every weight in the tile.
+
+    Interleaved words ``(bk//32, 3, bn)`` hold bit p in plane p;
+    plane-major words ``(n_planes, bk//32, bn)`` are MSB-first, so bit b
+    lives in plane ``2 - b`` (present only when that plane streams)."""
+    word = planes_blk[2 - bit] if plane_major else planes_blk[:, bit, :]
+    g = word.shape[0]
     # bit position j within each 32-code word, as an iota over a new axis
     j = jax.lax.broadcasted_iota(jnp.int32, (g, PLANE, bn), dimension=1)
-    code = jnp.zeros((g, PLANE, bn), dtype=jnp.int32)
-    for p in range(3):
-        word = planes_blk[:, p, :]  # (g, bn)
-        bit = (jax.lax.shift_right_logical(word[:, None, :], j)) & 1
-        code = code | (bit << p)
-    return code.reshape(bk, bn)
-
-
-def _unpack_planes_major(
-    planes_blk: jax.Array, bk: int, bn: int, n_planes: int
-) -> jax.Array:
-    """(n_planes, bk//32, bn) MSB-first plane-major words -> (bk, bn) codes.
-
-    Streamed plane p carries code bit (2 - p); absent trailing planes
-    contribute zero bits, exactly like a masked code stream.
-    """
-    g = bk // PLANE
-    j = jax.lax.broadcasted_iota(jnp.int32, (g, PLANE, bn), dimension=1)
-    code = jnp.zeros((g, PLANE, bn), dtype=jnp.int32)
-    for p in range(n_planes):
-        word = planes_blk[p]  # (g, bn)
-        bit = (jax.lax.shift_right_logical(word[:, None, :], j)) & 1
-        code = code | (bit << (2 - p))
-    return code.reshape(bk, bn)
+    return (jax.lax.shift_right_logical(word[:, None, :], j) & 1).reshape(
+        g * PLANE, bn)
 
 
 def _unpack(planes_blk, bk, bn, plane_major: bool, n_planes: int):
-    if plane_major:
-        return _unpack_planes_major(planes_blk, bk, bn, n_planes)
-    return _unpack_planes(planes_blk, bk, bn)
+    """Plane words of either layout -> (bk, bn) int32 codes.  Absent
+    trailing plane-major planes contribute zero bits, exactly like a
+    masked code stream."""
+    bits = range(3 - n_planes, 3) if plane_major else range(3)
+    code = jnp.zeros((bk, bn), dtype=jnp.int32)
+    for b in bits:
+        code = code | (_code_bit(planes_blk, b, bn, plane_major) << b)
+    return code
 
 
 def _planes_spec(plane_major: bool, n_planes: int, bk: int, bn: int):
@@ -157,15 +146,71 @@ def _qsq_matmul_kernel(
     )
 
 
+def _masked_weights(planes_blk, sc, variants, *, bk: int, bn: int,
+                    group_size: int, sign_mag: bool, plane_major: bool,
+                    n_planes: int, dtype) -> list:
+    """The weight tile decoded under each static mask of ``variants``, in
+    ``dtype`` for the MXU, from one unpack of the tile's streamed planes.
+
+    Sign-magnitude codes share their bits across the variants: with the
+    magnitude index ``idx = 2*b1 + b0`` the full level is
+    ``idx + (b1 & b0)`` (0, 1, 2, 4), the LSB-dropped level is ``2*b1``
+    and the sign-only mask leaves 0; the sign bit b2 flips the scale once
+    for all variants.  ``|level| * -scale == -|level| * scale`` in IEEE
+    arithmetic, so every weight equals ``decode(codes & mask) * scale``
+    bit for bit, except that a zero may come out as -0.  Table II offset
+    codes decode ``codes & mask`` per variant."""
+    ng = bk // group_size
+
+    def scaled(levels, scale):
+        w = levels.astype(jnp.float32).reshape(ng, group_size, bn) * scale
+        return w.reshape(bk, bn).astype(dtype)
+
+    if not sign_mag:
+        codes = _unpack(planes_blk, bk, bn, plane_major, n_planes)
+        return [scaled(_decode_codes(codes & mask), sc[:, None, :])
+                for mask in variants]
+    widest = MASK_VARIANTS.index(variants[0])  # fewest planes dropped
+    sign = _code_bit(planes_blk, 2, bn, plane_major).reshape(ng, group_size, bn)
+    scale = jnp.where(sign == 1, -sc[:, None, :], sc[:, None, :])
+    b1 = b0 = None
+    if widest < 2:
+        b1 = _code_bit(planes_blk, 1, bn, plane_major)
+    if widest == 0:
+        b0 = _code_bit(planes_blk, 0, bn, plane_major)
+    out = []
+    for mask in variants:
+        if mask == 0b111:
+            levels = ((b1 << 1) | b0) + (b1 & b0)
+        elif mask == 0b110:
+            levels = b1 << 1
+        else:
+            levels = jnp.zeros((bk, bn), jnp.int32)
+        out.append(scaled(levels, scale))
+    return out
+
+
+def _masked_dot(xs_ref, planes_blk, sc, variants, **kw) -> jax.Array:
+    """Sum over ``variants`` of ``xs_ref[i] @ weight under variants[i]``:
+    each x row sits in one variant's slice (zeros elsewhere), so its
+    output is its own variant's product plus exact zeros."""
+    ws = _masked_weights(planes_blk, sc, variants, dtype=xs_ref.dtype, **kw)
+    acc = None
+    for i, w in enumerate(ws):
+        d = jnp.dot(xs_ref[i], w, preferred_element_type=jnp.float32)
+        acc = d if acc is None else acc + d
+    return acc
+
+
 def _qsq_matmul_masked_kernel(
     xs_ref, planes_ref, scales_ref, o_ref, *,
     bk: int, group_size: int, sign_mag: bool, plane_major: bool,
-    demand_drop: int,
+    demand_drop: int, variants: tuple[int, ...],
 ):
     """Per-row plane-masked GEMM tile (see qsq_matvec._qsq_matvec_masked_kernel
-    for the variant-split contract): one weight-tile stream, one static mask
-    decode in VREGs per demanded variant, one dot per variant into the shared
-    output.  ``demand_drop`` prunes the variants no live row can select."""
+    for the variant-split contract): one weight-tile stream, one unpack,
+    one weight per static mask of ``variants`` (:func:`_masked_weights`),
+    one dot per variant into the shared output."""
     bn = o_ref.shape[1]
     k = pl.program_id(2)
 
@@ -173,25 +218,16 @@ def _qsq_matmul_masked_kernel(
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    codes = _unpack(planes_ref[...], bk, bn, plane_major, 3 - demand_drop)
-    decode = _decoder(sign_mag)
-    ng = bk // group_size
-    sc = scales_ref[...]
-    acc = None
-    for i, mask in enumerate(MASK_VARIANTS[demand_drop:]):
-        levels = decode(codes & mask).astype(jnp.float32)
-        w = (levels.reshape(ng, group_size, bn) * sc[:, None, :]).reshape(bk, bn)
-        d = jnp.dot(
-            xs_ref[i], w.astype(xs_ref.dtype), preferred_element_type=jnp.float32
-        )
-        acc = d if acc is None else acc + d
-    o_ref[...] += acc
+    o_ref[...] += _masked_dot(
+        xs_ref, planes_ref[...], scales_ref[...], variants, bk=bk, bn=bn,
+        group_size=group_size, sign_mag=sign_mag, plane_major=plane_major,
+        n_planes=3 - demand_drop)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("group_size", "bm", "bk", "bn", "interpret",
-                     "sign_mag", "plane_major", "demand_drop"),
+                     "sign_mag", "plane_major", "demand_drop", "variants"),
 )
 def qsq_matmul_masked(
     xs: jax.Array,
@@ -206,22 +242,24 @@ def qsq_matmul_masked(
     sign_mag: bool = False,
     plane_major: bool = False,
     demand_drop: int = 0,
+    variants: tuple[int, ...] | None = None,
 ) -> jax.Array:
     """Plane-masked sibling of :func:`qsq_matmul`:
-    xs (3 - demand_drop, M, K) -> (M, N) f32.
+    xs (len(variants), M, K) -> (M, N) f32.
 
-    xs[i] holds the x rows whose plane mask is
-    ``ref.MASK_VARIANTS[demand_drop + i]`` (other rows zero).  Same tiling
-    contract as the unmasked kernel.  With ``plane_major`` the weight block
-    only spans the ``3 - demand_drop`` demanded planes."""
+    ``variants`` (static; default ``ref.MASK_VARIANTS[demand_drop:]``) is
+    an ordered subset of that suffix, and xs[i] holds the x rows whose
+    plane mask is ``variants[i]`` (other rows zero).  Same tiling contract
+    as the unmasked kernel.  With ``plane_major`` the weight block only
+    spans the ``3 - demand_drop`` demanded planes."""
     nv, m, kdim = xs.shape
     n = planes.shape[-1]
     if not 0 <= demand_drop <= 2:
         raise ValueError(f"demand_drop must be 0..2, got {demand_drop}")
     n_planes = 3 - demand_drop
-    if nv != n_planes:
-        raise ValueError(
-            f"xs leading dim {nv} != {n_planes} demanded mask variants")
+    variants = mask_variants(demand_drop, variants)
+    if nv != len(variants):
+        raise ValueError(f"xs leading dim {nv} != {len(variants)} mask variants")
     _check_planes_shape(planes, kdim, n, plane_major)
     if scales.shape != (kdim // group_size, n):
         raise ValueError(f"scales shape {scales.shape} != {(kdim // group_size, n)}")
@@ -234,7 +272,8 @@ def qsq_matmul_masked(
     grid = (m // bm, n // bn, kdim // bk)
     kernel = functools.partial(
         _qsq_matmul_masked_kernel, bk=bk, group_size=group_size,
-        sign_mag=sign_mag, plane_major=plane_major, demand_drop=demand_drop)
+        sign_mag=sign_mag, plane_major=plane_major, demand_drop=demand_drop,
+        variants=variants)
     pshape, pmap = _planes_spec(plane_major, n_planes, bk, bn)
     return pl.pallas_call(
         kernel,

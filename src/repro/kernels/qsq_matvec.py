@@ -23,6 +23,13 @@ Layout matches qsq_matmul: x (M, K), planes (K//32, 3, N) int32 (or
 f32.  ``sign_mag``/``plane_major``/``demand_drop`` follow the qsq_matmul
 contract; since decode is weight-stream bound, demand-shortened plane-major
 reads cut the dominant roofline term almost linearly in planes demanded.
+
+The per-row masked sibling takes x pre-split into one slice per mask
+variant it unrolls: a static, ordered subset of
+``MASK_VARIANTS[demand_drop:]`` that holds only the masks some live row
+can select.  It unpacks each weight tile once and builds every variant's
+weight from the shared bits, so a variant costs a few VPU ops and one MXU
+pass, not a whole decode.
 """
 from __future__ import annotations
 
@@ -37,10 +44,11 @@ from repro.kernels.qsq_matmul import (
     PLANE,
     _check_planes_shape,
     _decoder,
+    _masked_dot,
     _planes_spec,
     _unpack,
 )
-from repro.kernels.ref import MASK_VARIANTS
+from repro.kernels.ref import mask_variants
 
 
 def _qsq_matvec_kernel(
@@ -73,17 +81,18 @@ def _qsq_matvec_kernel(
 def _qsq_matvec_masked_kernel(
     xs_ref, planes_ref, scales_ref, o_ref, acc_ref, *,
     bk: int, group_size: int, nk: int, sign_mag: bool, plane_major: bool,
-    demand_drop: int,
+    demand_drop: int, variants: tuple[int, ...],
 ):
-    """Per-row plane-masked GEMV: xs_ref (3 - demand_drop, M, bk) carries x
+    """Per-row plane-masked GEMV: xs_ref (len(variants), M, bk) carries x
     pre-split by mask variant (rows of other variants zeroed).  The weight
-    tile streams ONCE; it is decoded under each demanded static plane mask
-    in VREGs (``codes & mask`` — a dropped plane is a masked term of the
-    unpack) and each variant contracts its own x rows.  A row's accumulator
-    only ever receives its variant's product plus exact zeros, so per-row
-    output is bit-identical to the unmasked kernel on plane-truncated
-    weights.  ``demand_drop`` prunes variants no live row selects; with
-    ``plane_major`` the streamed weight block also shrinks to the demanded
+    tile streams ONCE and is unpacked once; each static mask of
+    ``variants`` then gets its weight from the shared bits
+    (``qsq_matmul._masked_weights``) and contracts its own x rows.  A row's
+    accumulator only ever receives its variant's product plus exact zeros,
+    so per-row output is bit-identical to the unmasked kernel on
+    plane-truncated weights (-0 and +0 aside).  ``variants`` holds only the
+    masks some live row can select; with ``plane_major`` the streamed
+    weight block also shrinks to the ``3 - demand_drop`` demanded
     planes."""
     bn = o_ref.shape[1]
     k = pl.program_id(1)
@@ -92,19 +101,10 @@ def _qsq_matvec_masked_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = _unpack(planes_ref[...], bk, bn, plane_major, 3 - demand_drop)
-    decode = _decoder(sign_mag)
-    ng = bk // group_size
-    sc = scales_ref[...]
-    acc = None
-    for i, mask in enumerate(MASK_VARIANTS[demand_drop:]):
-        levels = decode(codes & mask).astype(jnp.float32)
-        w = (levels.reshape(ng, group_size, bn) * sc[:, None, :]).reshape(bk, bn)
-        d = jnp.dot(
-            xs_ref[i], w.astype(xs_ref.dtype), preferred_element_type=jnp.float32
-        )
-        acc = d if acc is None else acc + d
-    acc_ref[...] += acc
+    acc_ref[...] += _masked_dot(
+        xs_ref, planes_ref[...], scales_ref[...], variants, bk=bk, bn=bn,
+        group_size=group_size, sign_mag=sign_mag, plane_major=plane_major,
+        n_planes=3 - demand_drop)
 
     @pl.when(k == nk - 1)
     def _flush():
@@ -113,7 +113,8 @@ def _qsq_matvec_masked_kernel(
 
 @functools.partial(
     jax.jit, static_argnames=("group_size", "bk", "bn", "interpret",
-                              "sign_mag", "plane_major", "demand_drop")
+                              "sign_mag", "plane_major", "demand_drop",
+                              "variants")
 )
 def qsq_matvec_masked(
     xs: jax.Array,
@@ -127,22 +128,24 @@ def qsq_matvec_masked(
     sign_mag: bool = False,
     plane_major: bool = False,
     demand_drop: int = 0,
+    variants: tuple[int, ...] | None = None,
 ) -> jax.Array:
     """Plane-masked sibling of :func:`qsq_matvec`:
-    xs (3 - demand_drop, M, K) -> (M, N).
+    xs (len(variants), M, K) -> (M, N).
 
-    xs[i] holds the x rows whose plane mask is
-    ``MASK_VARIANTS[demand_drop + i]`` (other rows zero); the dispatcher
-    builds it from the per-row plane_mask operand.  Same tiling contract as
-    the unmasked kernel."""
+    ``variants`` (static; default ``MASK_VARIANTS[demand_drop:]``) is an
+    ordered subset of that suffix, and xs[i] holds the x rows whose plane
+    mask is ``variants[i]`` (other rows zero); the dispatcher builds it
+    from the per-row plane_mask operand.  Same tiling contract as the
+    unmasked kernel."""
     nv, m, kdim = xs.shape
     n = planes.shape[-1]
     if not 0 <= demand_drop <= 2:
         raise ValueError(f"demand_drop must be 0..2, got {demand_drop}")
     n_planes = 3 - demand_drop
-    if nv != n_planes:
-        raise ValueError(
-            f"xs leading dim {nv} != {n_planes} demanded mask variants")
+    variants = mask_variants(demand_drop, variants)
+    if nv != len(variants):
+        raise ValueError(f"xs leading dim {nv} != {len(variants)} mask variants")
     _check_planes_shape(planes, kdim, n, plane_major)
     if scales.shape != (kdim // group_size, n):
         raise ValueError(f"scales shape {scales.shape} != {(kdim // group_size, n)}")
@@ -156,7 +159,8 @@ def qsq_matvec_masked(
     grid = (n // bn, nk)
     kernel = functools.partial(
         _qsq_matvec_masked_kernel, bk=bk, group_size=group_size, nk=nk,
-        sign_mag=sign_mag, plane_major=plane_major, demand_drop=demand_drop
+        sign_mag=sign_mag, plane_major=plane_major, demand_drop=demand_drop,
+        variants=variants,
     )
     pshape, pmap = _planes_spec(plane_major, n_planes, bk, bn)
     return pl.pallas_call(

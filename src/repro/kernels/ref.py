@@ -21,10 +21,29 @@ from repro.core.qsq import codes_to_levels, levels_to_codes, smcodes_to_levels
 # The three plane masks a quality tier can put on a row: keep all 3 code
 # planes, drop the LSB plane, drop the two LSB planes (drop = 0, 1, 2).
 # Fixed and ordered, so masked kernels unroll over them statically — a
-# per-row tier change is a data change, never a retrace.  Demand-driven
-# dispatch restricts a call to the suffix ``MASK_VARIANTS[demand_drop:]``:
-# with every live row at drop >= d, the first d variants are provably dead.
+# per-row tier change is a data change, never a retrace.  A masked call
+# unrolls a static subset ``variants``, in this order: at most the suffix
+# ``MASK_VARIANTS[demand_drop:]`` (with every live row at drop >= d, the
+# first d variants are provably dead), and on a leaf with a tier vector
+# only the masks its tiers at or above the demand floor select
+# (``PackedWeight.mask_variants``).  A row whose mask is outside the set
+# reads exact zeros.
 MASK_VARIANTS = (0b111, 0b110, 0b100)
+
+
+def mask_variants(demand_drop: int,
+                  variants: tuple[int, ...] | None = None) -> tuple[int, ...]:
+    """The static variant set of a masked call: ``variants`` checked to be
+    a non-empty, ordered subset of ``MASK_VARIANTS[demand_drop:]``, or
+    that whole suffix when ``variants`` is None."""
+    suffix = MASK_VARIANTS[demand_drop:]
+    if variants is None:
+        return suffix
+    variants = tuple(variants)
+    if not variants or variants != tuple(v for v in suffix if v in variants):
+        raise ValueError(f"mask variants {variants} are not an ordered "
+                         f"subset of {suffix} (demand_drop={demand_drop})")
+    return variants
 
 
 def _unpack_codes(planes: jax.Array, plane_major: bool, n_planes: int = 3):
@@ -92,20 +111,23 @@ def qsq_dequant_masked_ref(
 def qsq_matmul_masked_ref(
     xs: jax.Array, planes: jax.Array, scales: jax.Array, group_size: int, *,
     sign_mag: bool = False, plane_major: bool = False, demand_drop: int = 0,
+    variants: tuple[int, ...] | None = None,
 ) -> jax.Array:
-    """Per-row plane-masked matmul: xs (3 - demand_drop, M, K) -> (M, N) f32.
+    """Per-row plane-masked matmul: xs (len(variants), M, K) -> (M, N) f32.
 
-    ``xs[i]`` holds the rows of x whose plane mask is
-    ``MASK_VARIANTS[demand_drop + i]`` (all other rows zeroed).  Each variant
-    contracts against the weight decoded under that mask; a row's result is
-    exactly its variant's term because the other variants contribute exact
-    zeros — so row m equals ``x[m] @ dequant(truncate(drop_m))`` bit for bit.
-    With ``demand_drop > 0`` on plane-major planes only ``3 - demand_drop``
+    ``variants`` (static, default ``MASK_VARIANTS[demand_drop:]``; see
+    :func:`mask_variants`) lists the masks the call unrolls, and ``xs[i]``
+    holds the rows of x whose plane mask is ``variants[i]`` (all other rows
+    zeroed).  Each variant contracts against the weight decoded under that
+    mask; a row's result is exactly its variant's term because the other
+    variants contribute exact zeros — so row m equals
+    ``x[m] @ dequant(truncate(drop_m))`` bit for bit.  With
+    ``demand_drop > 0`` on plane-major planes only ``3 - demand_drop``
     planes are ever unpacked: the demand-shortened read.
     """
     n_planes = 3 - demand_drop
     out = None
-    for i, mask in enumerate(MASK_VARIANTS[demand_drop:]):
+    for i, mask in enumerate(mask_variants(demand_drop, variants)):
         w = qsq_dequant_masked_ref(
             planes, scales, group_size, mask, sign_mag=sign_mag,
             plane_major=plane_major, n_planes=n_planes)

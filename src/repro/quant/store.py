@@ -448,11 +448,34 @@ class PackedWeight(WeightStore):
         leaves, where skipping actually shortens the HBM read."""
         drop = 0
         if demand_tier is not None and self.tier_drops:
-            t = min(max(int(demand_tier), 0), len(self.tier_drops) - 1)
-            drop = min(self.tier_drops[t:])
+            drop = min(self._live_tier_drops(demand_tier))
         if self.plane_major:
             drop = max(drop, 3 - self.n_planes)
         return int(drop)
+
+    def _live_tier_drops(self, demand_tier: int | None) -> tuple[int, ...]:
+        """Drops of the tiers at or above ``demand_tier`` (every tier when
+        it is None) on a leaf with a tier vector."""
+        if demand_tier is None:
+            return self.tier_drops
+        t = min(max(int(demand_tier), 0), len(self.tier_drops) - 1)
+        return self.tier_drops[t:]
+
+    def mask_variants(self, demand_tier: int | None = None) -> tuple[int, ...]:
+        """Static mask variants a masked matmul at ``demand_tier`` unrolls:
+        of ``MASK_VARIANTS[demand_drop:]``, in that order, the masks that
+        some tier at or above the floor selects on this leaf.  A plan
+        that drops at most one plane never selects ``0b100``, so its
+        masked kernels decode at most two variants.  Without a tier
+        vector, the whole suffix."""
+        from repro.kernels.ref import MASK_VARIANTS  # deferred, as below
+
+        suffix = MASK_VARIANTS[self.demand_drop(demand_tier):]
+        if not self.tier_drops:
+            return suffix
+        live = {_trunc_code_mask(d)
+                for d in self._live_tier_drops(demand_tier)}
+        return tuple(v for v in suffix if v in live)
 
     def matmul(self, x, plane_mask: jax.Array | None = None,
                demand_tier: int | None = None):
@@ -469,9 +492,9 @@ class PackedWeight(WeightStore):
         tier index; combined with ``tier_drops`` it bounds how many
         trailing planes no row wants (:meth:`demand_drop`), and on
         plane-major leaves the kernel then streams only the demanded
-        planes from HBM.  Every row's ``plane_mask`` must drop at least
-        ``demand_drop`` planes — rows demanding a pruned variant read as
-        zeros."""
+        planes from HBM.  The kernel unrolls only :meth:`mask_variants`:
+        every row's ``plane_mask`` must be one of them — a row with any
+        other mask reads as zeros."""
         if self._stack():
             raise ValueError(
                 "matmul on a stacked PackedWeight — slice the stack axis "
@@ -512,6 +535,7 @@ class PackedWeight(WeightStore):
             plane_mask=plane_mask,
             sign_mag=self.sign_mag, plane_major=self.plane_major,
             demand_drop=self.demand_drop(demand_tier),
+            variants=self.mask_variants(demand_tier),
         )
         return out.astype(x.dtype).reshape(*lead, *rest)
 

@@ -23,7 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_arch
-from repro.kernels import dispatch, ops
+from repro.kernels import dispatch, ops, ref
 
 G = 16  # artifact.default_policy group size
 
@@ -63,9 +63,10 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile_for_chip(sharding, m, k, n, *, masked, demand_drop=0):
+def _compile_for_chip(sharding, m, k, n, *, masked, demand_drop=0,
+                      variants=None):
     p = dispatch.plan(m, k, n, G, backend="tpu")
-    n_planes = 3 - demand_drop
+    n_variants = len(ref.mask_variants(demand_drop, variants))
 
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
@@ -79,7 +80,8 @@ def _compile_for_chip(sharding, m, k, n, *, masked, demand_drop=0):
     if not gemv:
         kw["bm"] = p.bm
     if masked:
-        x = s((n_planes, p.pm, k), jnp.bfloat16)
+        kw["variants"] = variants
+        x = s((n_variants, p.pm, k), jnp.bfloat16)
         fn = ops.qsq_matvec_masked if gemv else ops.qsq_matmul_masked
     else:
         x = s((p.pm, k), jnp.bfloat16)
@@ -115,6 +117,32 @@ def test_deepseek_wd_gemv_compiles_for_v5e(one_chip):
     # tile, 1376, is not lane-aligned; the tile must drop to 256
     p = _compile_for_chip(one_chip, 8, 11008, 4096, masked=False)
     assert p.route == dispatch.ROUTE_GEMV
+
+
+def _deepseek_matmuls():
+    """(name, K, N) of the packed DeepSeek-7B matmuls at full width."""
+    c = get_arch("deepseek_7b")
+    return [("wq", c.d_model, c.n_heads * c.hd), ("wg_wu", c.d_model, c.d_ff),
+            ("wd", c.d_ff, c.d_model), ("head", c.d_model, c.vocab)]
+
+
+# the served variant sets of a plan that drops at most one plane: hi live
+# (two variants, all planes stream), or mid/lo alone (one, two planes)
+SERVED_VARIANTS = {"2var": (0, (0b111, 0b110)), "1var": (1, (0b110,))}
+
+
+@pytest.mark.parametrize("variants", list(SERVED_VARIANTS))
+@pytest.mark.parametrize("m", [16, 64], ids=["decode", "prefill"])
+@pytest.mark.parametrize("name,k,n", _deepseek_matmuls(),
+                         ids=[t[0] for t in _deepseek_matmuls()])
+def test_deepseek_masked_compiles_for_v5e(one_chip, name, k, n, m, variants):
+    # 16 decode slots take the GEMV, a 64-token admission the GEMM; both
+    # keep their HLO names whatever the variant set
+    demand_drop, vs = SERVED_VARIANTS[variants]
+    p = _compile_for_chip(one_chip, m, k, n, masked=True,
+                          demand_drop=demand_drop, variants=vs)
+    want = dispatch.ROUTE_GEMV if m <= dispatch.GEMV_M_MAX else dispatch.ROUTE_GEMM
+    assert p.route == want
 
 
 @pytest.mark.parametrize("m", [4, 64])

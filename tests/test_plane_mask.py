@@ -11,6 +11,8 @@ per-weight truncation error stays within the documented
 Property tests run under hypothesis when it is installed; on a clean
 interpreter they fall back to a fixed seed sweep of the same checks.
 """
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -32,13 +34,13 @@ from repro.quant.store import (
 )
 
 
-def _packed(k, n, g, seed):
+def _packed(k, n, g, seed, sign_mag=True):
     rng = np.random.RandomState(seed)
     w = jnp.asarray(rng.randn(k, n), jnp.float32)
     q = QSQWeight.from_tensor(
         quantize(w, QSQConfig(group_size=g, refit_alpha=True)), rest_ndim=1
     )
-    return q.pack()
+    return q.pack(sign_mag=sign_mag)
 
 
 def _check_masked_rows_match_truncated(m, kmul, n, g, seed, use_kernel):
@@ -122,6 +124,72 @@ else:  # pragma: no cover - fallback sweep on hypothesis-less interpreters
     ])
     def test_truncation_error_bound(kmul, n, g, seed):
         _check_truncation_error_bound(kmul, n, g, seed)
+
+
+# --------------------------------------------------------------------------
+# Variant sets: a leaf's tier plan picks the masks its kernels unroll
+# --------------------------------------------------------------------------
+# drops per tier (hi first): DEFAULT_TIERS on a leaf that mid drops, a plan
+# that drops two planes, and a non-monotone vector
+TIER_PLANS = {"default": (0, 1, 1), "two_planes": (0, 1, 2),
+              "nonmonotone": (1, 2, 0, 2)}
+
+
+def _record_variant_splits(monkeypatch):
+    """Record the leading dim of every variant-split xs that reaches a
+    masked kernel or the masked reference."""
+    from repro.kernels import ops, ref
+
+    seen = []
+    for mod, name in ((ops, "qsq_matvec_masked"), (ops, "qsq_matmul_masked"),
+                      (ref, "qsq_matmul_masked_ref")):
+        def spy(xs, *a, _fn=getattr(mod, name), **kw):
+            seen.append(xs.shape[0])
+            return _fn(xs, *a, **kw)
+        monkeypatch.setattr(mod, name, spy)
+    return seen
+
+
+@pytest.mark.parametrize("sign_mag", [True, False], ids=["sign_mag", "table2"])
+@pytest.mark.parametrize("m,route", [(4, "gemv"), (40, "gemm"), (4, "xla")])
+@pytest.mark.parametrize("plan", list(TIER_PLANS))
+def test_variant_set_rows_match_truncated(plan, m, route, sign_mag,
+                                          monkeypatch):
+    """At every demand floor the call unrolls exactly the leaf's variant
+    set (an ordered subset of the demand suffix, ``0b100`` only where a
+    tier drops two planes); each row equals the truncated weight's row,
+    and a row whose mask is outside the set reads exactly 0."""
+    tier_drops = TIER_PLANS[plan]
+    pw = dataclasses.replace(_packed(64, 48, 16, 11, sign_mag=sign_mag),
+                             tier_drops=tier_drops).to_plane_major()
+    rng = np.random.RandomState(12)
+    x = jnp.asarray(rng.randn(m, 64), jnp.float32)
+    table = np.asarray(pw.tier_plane_masks())
+    seen = _record_variant_splits(monkeypatch)
+    set_packed_matmul_kernel(route != "xla")
+    try:
+        want = {d: np.asarray(pw.truncate(d).matmul(x)) for d in (0, 1, 2)}
+        for demand in range(len(tier_drops)):
+            variants = pw.mask_variants(demand)
+            suffix = MASK_VARIANTS[pw.demand_drop(demand):]
+            assert variants == tuple(v for v in suffix if v in variants)
+            assert (0b100 in variants) == (2 in tier_drops[demand:])
+            tiers = rng.randint(demand, len(tier_drops), size=m)
+            masks = table[tiers]
+            outside = [v for v in MASK_VARIANTS if v not in variants]
+            if outside:
+                masks[0] = outside[0]
+            seen.clear()
+            got = np.asarray(pw.matmul(x, plane_mask=jnp.asarray(masks),
+                                       demand_tier=demand))
+            assert seen == [len(variants)]
+            for row, drop in enumerate(np.asarray(tier_drops)[tiers]):
+                if row == 0 and outside:
+                    np.testing.assert_array_equal(got[0], 0)
+                else:
+                    np.testing.assert_array_equal(got[row], want[drop][row])
+    finally:
+        set_packed_matmul_kernel(True)
 
 
 # --------------------------------------------------------------------------

@@ -288,6 +288,48 @@ def test_engine_demand_updates_without_retrace(stream_artifact, no_retrace):
     assert len(out[r_lo].tokens) == 8 and len(out[r_hi].tokens) == 2
 
 
+def test_engine_masked_calls_unroll_only_selectable_variants(monkeypatch):
+    """On DEFAULT_TIERS no tier drops two planes: every masked call unrolls
+    at most two variants at floor hi and one at floor lo; at floor mid, one
+    on the leaves mid already truncates.  The trace-time counters show the
+    pruning against the demand suffix."""
+    cfg = ArchConfig(name="smollm-like", family="dense", n_layers=2,
+                     d_model=64, n_heads=4, n_kv=2, d_ff=128, vocab=256,
+                     dtype=jnp.float32, remat=False)
+    model = Model(cfg)
+    art = api.compress(model, init_params(jax.random.PRNGKey(1),
+                                          model.param_descs()))
+    eng = art.engine(quality="hi", batch_slots=2, max_prompt=6, max_len=16)
+    calls = []
+    real = dispatch.packed_matmul
+
+    def spy(*a, **kw):
+        if kw.get("plane_mask") is not None:
+            calls.append(len(kw["variants"]))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(dispatch, "packed_matmul", spy)
+    dispatch.reset_counters()
+    by_floor = {}
+    for q in art.quality_names():  # one fresh trace per demand floor
+        calls.clear()
+        eng.submit([3, 1], max_new=2, quality=q)
+        eng.run_until_drained()
+        by_floor[q] = list(calls)
+    assert by_floor["hi"] and max(by_floor["hi"]) == 2
+    assert set(by_floor["mid"]) == {1, 2} and set(by_floor["lo"]) == {1}
+    leaves = [leaf for leaf in jax.tree_util.tree_leaves(
+        eng.params, is_leaf=lambda x: hasattr(x, "tier_drops"))
+        if getattr(leaf, "tier_drops", None)]
+    assert leaves and all(
+        len(leaf.mask_variants(1)) == len(set(leaf.tier_drops[1:]))
+        for leaf in leaves)
+    t = dispatch.traffic
+    assert t["mask_variants"] == sum(map(sum, by_floor.values()))
+    assert 3 * t["mask_variants"] <= 2 * t["mask_variants_suffix"]
+    dispatch.reset_counters()
+
+
 def test_engine_stream_meter_all_lo_under_half_of_all_hi(stream_artifact):
     """ISSUE acceptance: all-lo bytes-read-per-token <= 0.5x all-hi
     (analytic meter; the tier ladder keeps one plane at lo, so the exact
