@@ -3,8 +3,11 @@
 ``BENCHMARK.json`` at the checkout root names each cell's configuration
 and traffic mix; the configuration entry names its file.  A traffic mix
 ``<mix>`` is ``traffic/<mix>.json`` and a metric ``<name>`` is read by
-``metrics/<name>.py`` (a function ``read(rec)``).  Adding a cell, a mix or
-a metric is adding a file and an entry; no existing file changes.
+``metrics/<name>.py`` (a function ``read(rec)``).  A configuration names
+its architecture by ``reference``: the plain reference
+``references/<reference>.py`` and the program adapter
+``programs/<reference>.py``.  Adding a cell, a mix, a metric or an
+architecture is adding files and entries; no existing file changes.
 
 A metric with a ``workloads`` list is reported in those cells.  An
 end-to-end metric without one is reported in every cell; a per-layer
@@ -15,7 +18,7 @@ reports without an edit to an existing entry.
 from __future__ import annotations
 
 import dataclasses
-import importlib
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -62,17 +65,31 @@ def load(cell: str, root: Path = ROOT, here: Path = HERE) -> Cell:
     )
 
 
-def reader(metric: str, here: Path = HERE):
-    """The ``read`` function of ``metrics/<metric>.py``."""
-    path = here / "metrics" / f"{metric}.py"
+@functools.cache
+def _module(here: Path, kind: str, name: str):
+    """``<here>/<kind>/<name>.py``, loaded once."""
+    path = here / kind / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no {kind} module {name!r}: add {path.relative_to(here.parents[1])}")
     spec = importlib.util.spec_from_file_location(
-        f"benchmarks.chip.metrics.{metric}", path)
+        f"benchmarks.chip.{kind}.{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
-def reference(config: dict):
+def reader(metric: str, here: Path = HERE):
+    """The ``read`` function of ``metrics/<metric>.py``."""
+    return _module(here, "metrics", metric).read
+
+
+def reference(config: dict, here: Path = HERE):
     """The plain reference module the configuration names."""
-    return importlib.import_module(
-        f"benchmarks.chip.references.{config['reference']}")
+    return _module(here, "references", config["reference"])
+
+
+def adapter(config: dict, here: Path = HERE):
+    """The program adapter of the configuration's reference: its
+    ``arch_config(config)`` gives the program's ``ArchConfig``."""
+    return _module(here, "programs", config["reference"])

@@ -30,9 +30,10 @@ def readings(cell, seed: int, seconds: float) -> dict:
     from benchmarks.chip.driver import Driver
 
     cfg, q = cell.config, cell.config["quant"]
-    ref = cells.reference(cfg)
+    ref = cells.reference(cfg, cell.here)
     t0 = time.perf_counter()
-    eng = program.build(cfg, ref, seed, log=lambda m: print(m, file=sys.stderr))
+    eng = program.build(cfg, ref, seed, log=lambda m: print(m, file=sys.stderr),
+                        here=cell.here)
     plan_faults = program.tier_plan_faults(eng, q)
     program.warm(eng, cell.traffic, cfg["vocab_size"])
     drv = Driver(eng, cell.traffic, seed, cfg["vocab_size"])

@@ -17,6 +17,7 @@ outside it.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import time
 
 from benchmarks.chip import loadgen
@@ -30,6 +31,15 @@ def _spans(on: bool):
     import jax
 
     return jax.profiler.TraceAnnotation
+
+
+def _fields(info) -> dict:
+    """Every field of a ``StepInfo``, tuples as lists."""
+    out = {}
+    for f in dataclasses.fields(info):
+        v = getattr(info, f.name)
+        out[f.name] = list(v) if isinstance(v, tuple) else v
+    return out
 
 
 class Driver:
@@ -58,8 +68,7 @@ class Driver:
             info = self.eng.step()
         t1 = time.perf_counter()
         i = len(self.steps)
-        self.steps.append({"t0": t0, "t1": t1, "admitted": list(info.admitted),
-                           "live": info.live, "demand": info.demand})
+        self.steps.append({"t0": t0, "t1": t1, **_fields(info)})
         for rid in info.admitted:
             self.requests[rid]["admitted_step"] = i
         for rid in info.finished:

@@ -3,29 +3,19 @@ seed, ``repro.api.compress`` and the per-request packed engine, warmed up
 on the shapes the cell's traffic uses."""
 from __future__ import annotations
 
-import dataclasses
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
 
-from benchmarks.chip import weights, work
+from benchmarks.chip import cells, weights, work
 
 
-def arch_config(config: dict):
-    """The program's ArchConfig at the sizes ``config`` states, on the
-    architecture module it names."""
-    from repro.configs import get_arch
-
-    arch = dataclasses.replace(
-        get_arch(config["arch"]), n_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"], d_ff=config["intermediate_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv=config["num_key_value_heads"], vocab=config["vocab_size"],
-        rope_theta=config["rope_theta"])
-    if arch.family != "dense" or arch.head_dim or arch.window or arch.qk_norm:
-        raise ValueError(f"{config['arch']} is not a plain dense decoder")
-    return arch
+def arch_config(config: dict, here: Path = cells.HERE):
+    """The program's ArchConfig for ``config``, from the adapter of the
+    reference it names (``programs/<reference>.py``)."""
+    return cells.adapter(config, here).arch_config(config)
 
 
 def check_layout(model, specs: dict) -> None:
@@ -42,9 +32,11 @@ def check_layout(model, specs: dict) -> None:
                          f"reference's: {sorted(set(got.items()) ^ set(want.items()))}")
 
 
-def build(config: dict, ref, seed: int, log=lambda msg: None):
+def build(config: dict, ref, seed: int, log=lambda msg: None,
+          here: Path = cells.HERE):
     """The per-request packed engine serving ``config`` with weights from
-    ``seed``, its served tree on the default device.
+    ``seed``, its served tree on the default device; the program's
+    architecture comes from the adapter under ``here``.
 
     The weights are drawn on the default device; compression runs on the
     host's CPU device, the offline step of the edge flow, where its f32
@@ -57,7 +49,7 @@ def build(config: dict, ref, seed: int, log=lambda msg: None):
     from repro.models.api import Model
 
     t = time.perf_counter()
-    model = Model(arch_config(config))
+    model = Model(arch_config(config, here))
     specs = ref.leaf_specs(config, config["quant"]["group"])
     check_layout(model, specs)
     params = jax.block_until_ready(weights.draw_tree(seed, specs))

@@ -4,16 +4,20 @@ A record is a plain dict, so a test can build one by hand:
 
     window     [t_open, t_close]: host seconds (``time.perf_counter``)
     steps      one entry per ``engine.step()``: ``t0``, ``t1`` (its start
-               and its return, which ends in a device sync), ``admitted``
-               (request ids), ``live`` (decoding lanes), ``demand``
-               (plane-demand floor of its decode dispatch, None if none)
+               and its return, which ends in a device sync) and every field
+               of the engine's ``StepInfo`` (tuples as lists), among them
+               ``admitted`` (request ids), ``live`` (decoding lanes),
+               ``demand`` (plane-demand floor of its decode dispatch, None
+               if none)
     requests   rid -> ``due`` (host seconds: scheduled arrival in an open
                loop, submission in a closed one), ``prompt_len``,
                ``max_new``, ``tier``, ``admitted_step``,
                ``finished_step`` (indices into ``steps``, None if never),
                ``n_tokens``, ``done``
-    batch_slots, setup_s, config, peaks, trace (reduced device trace or
-    None), memory_peak_bytes
+    config     what the work-counting readers need (``work_config``), with
+               the configuration itself under ``model``
+    trace      the reduced device trace (``trace.reduce``) or None
+    batch_slots, setup_s, peaks, memory_peak_bytes
 
 Tokens are placed by step: a request admitted at step ``a`` gets its
 first token from the admission prefill and its second from the decode
@@ -88,11 +92,19 @@ def p95(values) -> float:
 
 
 def work_config(cfg: dict, ref) -> dict:
-    """What the work-counting readers need of a configuration."""
+    """What the work-counting readers need of a configuration.  The leaves
+    the reference lists in ``ROUTED`` (rows routed to experts, not read by
+    every live row) stay out of ``packed_shapes`` and ``tier_vectors``,
+    which count dense leaves, and are given under ``routed_shapes``;
+    ``matmul_shapes`` is what one token passes through."""
     shapes = ref.matmul_shapes(cfg)
     q = cfg["quant"]
+    routed = set(getattr(ref, "ROUTED", ()))
+    dense = [p for p in q["packed"] if p not in routed]
     return {"matmul_shapes": shapes,
-            "packed_shapes": {p: shapes[p] for p in q["packed"]},
+            "packed_shapes": {p: shapes[p] for p in dense},
+            "routed_shapes": {p: shapes[p] for p in sorted(routed)},
             "head": ref.HEAD, "group": q["group"], "tier_order": q["tiers"],
-            "tier_vectors": work.tier_vectors(q["drops"], q["tiers"], q["packed"]),
-            "attention_flops_per_context": ref.attention_flops_per_token(cfg, 1.0)}
+            "tier_vectors": work.tier_vectors(q["drops"], q["tiers"], dense),
+            "attention_flops_per_context": ref.attention_flops_per_token(cfg, 1.0),
+            "model": cfg}

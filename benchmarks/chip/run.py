@@ -92,11 +92,11 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
                                         work_config)
 
     cfg, traffic = cell.config, cell.traffic
-    ref = cells.reference(cfg)
+    ref = cells.reference(cfg, cell.here)
     dev = jax.devices()[0]
     pk = peaks.peak(dev.device_kind) if dev.platform == "tpu" else None
     compiles = _CompileCount()
-    eng = program.build(cfg, ref, seed, log=_log)
+    eng = program.build(cfg, ref, seed, log=_log, here=cell.here)
     plan_faults = program.tier_plan_faults(eng, cfg["quant"])
     t = time.perf_counter()
     program.warm(eng, traffic, cfg["vocab_size"])

@@ -5,21 +5,25 @@ The device's operations are the events of the ``XLA Ops`` line of each
 (``%qsq_matvec_masked.43 = f32[8,49152] custom-call(...)``); the programs
 it ran are the events of its ``XLA Modules`` line (``jit_admit(<hash>)``).
 A ``while`` op (the scanned layer loop) spans the operations inside it,
-so it counts toward busy time but is not listed among the top operations.  The benchmark's own host spans (``bench.submit``,
-``bench.step``, ``bench.poll``, ``bench.wait``) come from
-``jax.profiler.TraceAnnotation`` and sit on the host plane, on the same
-clock.  The traced window runs from the first host span to the end of the
-last step that returned inside the benchmark's window.
+so it counts toward busy time but is not listed among the operations.
+The benchmark's own host spans (``bench.submit``, ``bench.step``,
+``bench.poll``, ``bench.wait``) and the program's (``serve.step`` and the
+spans inside it) come from ``jax.profiler.TraceAnnotation`` and sit on the
+host plane, on the same clock.  The traced window runs from the first
+``bench.*`` span to the end of the last step that returned inside the
+benchmark's window.
 """
 from __future__ import annotations
 
 import collections
 import glob
+import heapq
 import os
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-SPAN_PREFIX = "bench."
+SPAN_PREFIX = "bench."  # the benchmark's spans: the window, ``idle_gaps``
+PROGRAM_PREFIX = "serve."  # the program's spans: ``spans``, ``idle_by_span``
 # kernel event names, matched as substrings
 KERNELS = {"gemv": ("qsq_matvec",), "gemm": ("qsq_matmul",)}
 ADMIT_PROGRAM = "jit_admit"  # the admission program: prefill + lane insert
@@ -36,7 +40,8 @@ def op_name(event_name: str) -> str:
 
 
 def read_xplane(path: str) -> dict:
-    """{'ops', 'modules', 'spans'}: lists of (name, start_ns, end_ns)."""
+    """{'ops', 'modules', 'spans'}: lists of (name, start_ns, end_ns);
+    ``spans`` holds the benchmark's and the program's host spans."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -52,7 +57,8 @@ def read_xplane(path: str) -> dict:
             for line in plane.lines:
                 out["spans"] += [(e.name, e.start_ns, e.end_ns)
                                  for e in line.events
-                                 if e.name.startswith(SPAN_PREFIX)]
+                                 if e.name.startswith((SPAN_PREFIX,
+                                                       PROGRAM_PREFIX))]
     return out
 
 
@@ -79,10 +85,31 @@ def window(spans, n_window_steps: int) -> tuple[float, float]:
     return lo, steps[min(n_window_steps, len(steps)) - 1][1]
 
 
+def idle_by_span(gaps, spans) -> collections.Counter:
+    """Nanoseconds of each gap (in time order) put down to the innermost
+    (shortest) span around its middle, or to ``outside spans``."""
+    spans = sorted(spans, key=lambda s: s[1])
+    idle, open_, i = collections.Counter(), [], 0
+    for a, b in gaps:
+        mid = (a + b) / 2
+        while i < len(spans) and spans[i][1] <= mid:
+            name, start, end = spans[i]
+            heapq.heappush(open_, (end - start, i, end, name))
+            i += 1
+        while open_ and open_[0][2] < mid:  # ended: every later middle is later
+            heapq.heappop(open_)
+        idle[open_[0][3] if open_ else "outside spans"] += b - a
+    return idle
+
+
 def reduce(ev: dict, n_window_steps: int) -> dict:
-    """Busy and idle seconds, kernel seconds and the top device operations
-    and idle gaps of the traced window."""
-    lo, hi = window(ev["spans"], n_window_steps)
+    """Busy and idle seconds, kernel seconds, the seconds of every device
+    operation (``op_s``; ``device_ops`` its top ten), the program's spans
+    in the window (``spans``: name, start and end in seconds from the
+    window's start) and the idle time by innermost span: ``idle_gaps``
+    over the benchmark's spans, ``idle_by_span`` over both."""
+    bench = [s for s in ev["spans"] if s[0].startswith(SPAN_PREFIX)]
+    lo, hi = window(bench, n_window_steps)
     ops = _clip(ev["ops"], lo, hi)
     busy = merge((a, b) for _, a, b in ops)
     busy_ns = sum(b - a for a, b in busy)
@@ -96,23 +123,23 @@ def reduce(ev: dict, n_window_steps: int) -> dict:
     modules = _clip(ev["modules"], lo, hi)
     admit_s = sum(b - a for n, a, b in modules if n.startswith(ADMIT_PROGRAM)) / 1e9
     decode_s = sum(b - a for n, a, b in modules if n.startswith(DECODE_PROGRAM)) / 1e9
-    # idle gaps, each put down to the innermost host span around its middle
-    spans = sorted(_clip(ev["spans"], lo, hi), key=lambda s: s[1])
-    idle = collections.Counter()
     edges = [lo] + [x for ab in busy for x in ab] + [hi]
-    for a, b in zip(edges[::2], edges[1::2], strict=True):
-        if b <= a:
-            continue
-        mid = (a + b) / 2
-        inner = [s for s in spans if s[1] <= mid <= s[2]]
-        label = min(inner, key=lambda s: s[2] - s[1])[0] if inner else "outside spans"
-        idle[label] += b - a
+    gaps = [(a, b) for a, b in zip(edges[::2], edges[1::2], strict=True) if b > a]
+    clipped = _clip(ev["spans"], lo, hi)
+    idle = idle_by_span(gaps, [s for s in clipped if s[0].startswith(SPAN_PREFIX)])
+    program = sorted(((n, a, b) for n, a, b in ev["spans"]
+                      if n.startswith(PROGRAM_PREFIX) and lo <= a and b <= hi),
+                     key=lambda s: s[1])
     return {
         "window_s": (hi - lo) / 1e9, "busy_s": busy_ns / 1e9,
         "gemv_s": kernel_s["gemv"], "gemm_s": kernel_s["gemm"],
         "admit_s": admit_s, "decode_s": decode_s,
         "device_ops": [[n, v / 1e9] for n, v in by_name.most_common(TOP)],
         "idle_gaps": [[n, v / 1e9] for n, v in idle.most_common(TOP)],
+        "op_s": {n: v / 1e9 for n, v in by_name.most_common()},
+        "spans": [(n, (a - lo) / 1e9, (b - lo) / 1e9) for n, a, b in program],
+        "idle_by_span": {n: v / 1e9 for n, v in
+                         idle_by_span(gaps, clipped).most_common()},
     }
 
 

@@ -4,7 +4,9 @@
 two-layer llama configuration with grouped-query attention, a closed and
 an open traffic mix and a ``BENCHMARK.json`` naming both cells, so a test
 drives the real harness on the CPU (Pallas kernels interpreted) in
-seconds.
+seconds.  ``add_toy_architecture(root)`` adds to such a copy, as new files
+only, an architecture of its own: a reference that routes one leaf, its
+program adapter, a configuration and a cell.
 """
 from __future__ import annotations
 
@@ -60,3 +62,29 @@ def tiny_root(tmp: Path) -> Path:
         m.pop("workloads", None)
     (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
     return tmp
+
+
+# the llama reference with its MLP down projection routed to experts
+TOY_REFERENCE = """from benchmarks.chip.references.llama import *  # noqa: F403
+
+ROUTED = {"blocks/mlp/wd"}
+"""
+TOY_ADAPTER = "from benchmarks.chip.programs.llama import arch_config  # noqa: F401\n"
+TOY_CONFIG = dict(TINY_CONFIG, reference="toy")
+
+
+def add_toy_architecture(root: Path) -> None:
+    """Adds ``references/toy.py``, ``programs/toy.py``,
+    ``configs/toy.json`` and the cell ``toy.closed`` to a tiny copy."""
+    here = root / "benchmarks" / "chip"
+    (here / "references" / "toy.py").write_text(TOY_REFERENCE)
+    (here / "programs" / "toy.py").write_text(TOY_ADAPTER)
+    (here / "configs" / "toy.json").write_text(json.dumps(TOY_CONFIG))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "toy", "source": "test",
+                             "file": "benchmarks/chip/configs/toy.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "toy.closed", "config": "toy",
+                               "traffic": "tiny-closed", "chips": 1,
+                               "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))

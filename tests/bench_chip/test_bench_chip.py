@@ -5,14 +5,18 @@ refusal to run without a TPU."""
 from __future__ import annotations
 
 import copy
+import dataclasses
 import json
 import os
 import subprocess
 import sys
+from types import SimpleNamespace
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
-from bench_chip_helpers import CLOSED, OPEN, ROOT, TINY_CONFIG, tiny_root
+from bench_chip_helpers import (CLOSED, OPEN, ROOT, TINY_CONFIG,
+                                add_toy_architecture, tiny_root)
 
 from benchmarks.chip import cells, loadgen, peaks, trace, work
 from benchmarks.chip.record import check_token_steps, token_steps
@@ -148,6 +152,125 @@ def test_files_added_to_a_copy_are_found_by_name(tmp_path):
     assert after == before  # nothing that was there changed
 
 
+# a reader for each thing an architecture's own reader can find in a record
+TOY_READERS = {
+    "toy_expert_ms": "1e3 * rec['trace']['op_s']['toy_expert']",
+    "toy_steps": "sum(n == 'serve.step' for n, _, _ in rec['trace']['spans'])",
+    "toy_sync_idle_ms": "1e3 * rec['trace']['idle_by_span']['serve.decode.sync']",
+    "toy_drafted": "sum(s['drafted'] for s in rec['steps'])",
+    "toy_expert_width": "rec['config']['model']['intermediate_size']",
+}
+
+
+def test_an_architecture_added_to_a_copy_is_found_by_name(tmp_path):
+    """A new architecture is new files: its reference (with a routed leaf),
+    its program adapter, a configuration, a cell and readers of the
+    record's device ops, program spans, idle by span, step fields and
+    configuration.  Every file that was there stays as it was."""
+    from benchmarks.chip import program
+    from benchmarks.chip.record import work_config
+
+    root = tiny_root(tmp_path)
+    here = root / "benchmarks" / "chip"
+    before = {p: p.read_bytes() for p in here.rglob("*") if p.is_file()}
+    add_toy_architecture(root)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    for name, expr in TOY_READERS.items():
+        (here / "metrics" / f"{name}.py").write_text(
+            f"def read(rec):\n    return {expr}\n")
+        bench["per_layer"].append({
+            "name": name, "unit": "1", "better": "higher",
+            "source": "device_trace", "layer": "experts",
+            "moves": "itl_p95_ms", "workloads": ["toy.closed"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    cell = cells.load("toy.closed", root, here)
+    ref = cells.reference(cell.config, here)
+    assert ref.__file__ == str(here / "references" / "toy.py")
+    assert ref.ROUTED == {"blocks/mlp/wd"}
+    assert cells.adapter(cell.config, here).__file__ == str(here / "programs" / "toy.py")
+    assert program.arch_config(cell.config, here).d_model == TINY_CONFIG["hidden_size"]
+    assert set(TOY_READERS) <= {m["name"] for m in cell.per_layer}
+    assert not set(TOY_READERS) & {m["name"] for m in
+                                   cells.load("tiny.closed", root, here).per_layer}
+
+    rec = _synthetic()
+    for s in rec["steps"]:
+        s["drafted"] = 2
+    rec["config"] = work_config(cell.config, ref)
+    assert "blocks/mlp/wd" in rec["config"]["routed_shapes"]
+    assert "blocks/mlp/wd" not in rec["config"]["packed_shapes"]
+    ev = _events()
+    ev["spans"] += SERVE_SPANS
+    ev["ops"].append(("%toy_expert.3 = f32[8] custom-call(...)", 1400, 1450))
+    rec["trace"] = trace.reduce(ev, n_window_steps=2)
+    got = {name: cells.reader(name, here)(rec) for name in TOY_READERS}
+    assert got == pytest.approx({"toy_expert_ms": 50e-6, "toy_steps": 2,
+                                 "toy_sync_idle_ms": 550e-6, "toy_drafted": 160,
+                                 "toy_expert_width": 128})
+    after = {p: p.read_bytes() for p in before}
+    assert after == before  # nothing that was there changed
+
+
+def test_a_missing_adapter_names_the_file_to_add(tmp_path):
+    here = tiny_root(tmp_path) / "benchmarks" / "chip"
+    with pytest.raises(FileNotFoundError, match="benchmarks/chip/programs/moe.py"):
+        cells.adapter({"reference": "moe"}, here)
+
+
+# ArchConfig fields that program.arch_config gave before the adapters
+ARCH_BEFORE_ADAPTERS = {
+    "deepseek-7b": dict(name="deepseek-7b", n_layers=6, d_model=4096,
+                        n_heads=32, n_kv=32, d_ff=11008, vocab=102400,
+                        source="arXiv:2401.02954; hf"),
+    "smollm-135m": dict(name="smollm-135m", n_layers=30, d_model=576,
+                        n_heads=9, n_kv=3, d_ff=1536, vocab=49152,
+                        source="hf:HuggingFaceTB/SmolLM-135M; hf"),
+}
+ARCH_SHARED = dict(family="dense", head_dim=0, qk_norm=False, window=None,
+                   rope_theta=10000.0, moe=None, ssm_state=0, ssm_head_dim=64,
+                   ssm_groups=1, ssm_chunk=256, hybrid=None, enc_layers=0,
+                   enc_seq=1500, cross_every=0, vision_tokens=1024,
+                   dtype=jnp.bfloat16, remat=True)
+
+
+@pytest.mark.parametrize("name", sorted(ARCH_BEFORE_ADAPTERS))
+def test_llama_adapter_gives_the_architecture_as_before(name):
+    from benchmarks.chip import program
+    from benchmarks.chip.programs import llama
+
+    cfg = json.loads((ROOT / f"benchmarks/chip/configs/{name}.json").read_text())
+    arch = llama.arch_config(cfg)
+    assert dataclasses.asdict(arch) == dict(ARCH_SHARED, **ARCH_BEFORE_ADAPTERS[name])
+    assert program.arch_config(cfg) == arch
+    with pytest.raises(ValueError, match="not a plain dense decoder"):
+        llama.arch_config(dict(cfg, arch="mamba2_1_3b"))
+
+
+def test_a_step_record_keeps_every_field_of_the_engines_step_info():
+    """Each step record holds every ``StepInfo`` field, tuples as lists,
+    a field that a later program adds among them."""
+    from benchmarks.chip.driver import Driver
+    from repro.serve.engine import StepInfo
+
+    @dataclasses.dataclass(frozen=True)
+    class Later(StepInfo):
+        routed_rows: tuple[int, ...] = ()
+
+    info = Later(admitted=(7,), finished=(), timed_out=(3,), live=3, demand=1,
+                 cost=0.5, drafted=2, accepted=1, routed_rows=(4, 0, 2))
+    drv = Driver(SimpleNamespace(step=lambda: info), CLOSED, 1, 256)
+    drv.requests[7] = {"admitted_step": None, "finished_step": None}
+    drv.step()
+    (s,) = drv.steps
+    assert set(s) == {"t0", "t1"} | {f.name for f in dataclasses.fields(Later)}
+    assert {k: s[k] for k in ("admitted", "timed_out", "live", "demand", "cost",
+                              "routed_rows")} == {
+        "admitted": [7], "timed_out": [3], "live": 3, "demand": 1, "cost": 0.5,
+        "routed_rows": [4, 0, 2]}
+    assert drv.requests[7]["admitted_step"] == 0
+
+
 def test_a_per_layer_metric_without_a_list_follows_what_it_moves(tmp_path):
     """A per-layer metric with no ``workloads`` list is reported in every
     cell that reports the end-to-end metric it moves, and in no other."""
@@ -234,6 +357,40 @@ def test_gemv_roofline_by_hand_on_a_synthetic_deepseek_record():
     assert cells.reader("gemv_roofline")(rec) == pytest.approx(50.0)
 
 
+def test_a_routed_leaf_leaves_the_gemv_count():
+    """A leaf the reference lists in ``ROUTED`` is read by the rows routed
+    to it, not by every live row: the dense GEMV count leaves it out, by
+    hand as in the test above, and ``routed_shapes`` gives it instead."""
+    from benchmarks.chip.record import window_steps, work_config
+    from benchmarks.chip.references import llama
+
+    cfg = json.loads((ROOT / "benchmarks/chip/configs/deepseek-7b.json").read_text())
+    routed = SimpleNamespace(
+        ROUTED={"blocks/mlp/wd"}, HEAD=llama.HEAD,
+        matmul_shapes=llama.matmul_shapes,
+        attention_flops_per_token=llama.attention_flops_per_token)
+    rec = _synthetic()
+    rec["config"] = work_config(cfg, routed)
+    rec["peaks"] = peaks.peak("TPU v5 lite")
+    shapes = llama.matmul_shapes(cfg)
+    assert rec["config"]["matmul_shapes"] == shapes
+    assert rec["config"]["routed_shapes"] == {"blocks/mlp/wd": shapes["blocks/mlp/wd"]}
+    assert "blocks/mlp/wd" not in rec["config"]["packed_shapes"]
+    assert "blocks/mlp/wd" not in rec["config"]["tier_vectors"]
+    assert rec["config"]["model"] is cfg
+    dense = set(cfg["quant"]["packed"]) - {"blocks/mlp/wd"}
+    per_dispatch = sum(n * (k * m * 3 / 8 + k // 16 * m * 4 + 4 * k * 2 + 4 * m * 2)
+                       for p, (n, k, m) in shapes.items() if p in dense)
+    _, k, v = shapes["embed/head"]
+    per_admission = k * v * 3 / 8 + k // 16 * v * 4 + k * 2 + v * 2
+    steps = window_steps(rec)
+    admissions = sum(len(s["admitted"]) for s in steps)
+    least = (len(steps) * per_dispatch + admissions * per_admission) / 819e9
+    rec["trace"] = {"gemv_s": 2 * least}
+    assert cells.reader("gemv_roofline")(rec) == pytest.approx(50.0)
+    assert work_config(cfg, llama)["packed_shapes"].keys() == set(cfg["quant"]["packed"])
+
+
 def test_every_per_layer_reader_reads_a_traced_record():
     """Each per-layer metric of the benchmark reads a number from a traced
     record of a v5e run, and a share of a roofline or peak stays in
@@ -246,7 +403,9 @@ def test_every_per_layer_reader_reads_a_traced_record():
     rec["config"] = work_config(cfg, llama)
     rec["peaks"] = peaks.peak("TPU v5 lite")
     rec["trace"] = {"window_s": 0.7, "busy_s": 0.5, "gemv_s": 0.5,
-                    "gemm_s": 0.05, "admit_s": 0.1, "decode_s": 0.5}
+                    "gemm_s": 0.05, "admit_s": 0.1, "decode_s": 0.5,
+                    "spans": [("serve.step", 0.0, 0.004),
+                              ("serve.decode.sync", 0.001, 0.003)]}
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     for m in bench["per_layer"]:
         v = cells.reader(m["name"])(rec)
@@ -290,8 +449,8 @@ def test_unknown_device_kind_raises():
 
 
 # -- trace reduction ------------------------------------------------------
-def test_trace_reduction_on_synthetic_events():
-    ev = {
+def _events() -> dict:
+    return {
         "spans": [("bench.submit", 0, 100), ("bench.step", 100, 1100),
                   ("bench.step", 1200, 2200), ("bench.wait", 2200, 3000),
                   ("bench.step", 3000, 4000)],
@@ -302,6 +461,18 @@ def test_trace_reduction_on_synthetic_events():
                 ("%fusion.2 = f32[8] fusion(...)", 3100, 3900)],  # after the window
         "modules": [("jit_admit(3)", 1250, 2100), ("jit_cont_step", 150, 800)],
     }
+
+
+# the program's spans inside the first two steps, and a step after the window
+SERVE_SPANS = [("serve.step", 110, 1090), ("serve.decode", 120, 1080),
+               ("serve.decode.dispatch", 120, 160),
+               ("serve.decode.sync", 160, 1070),
+               ("serve.step", 1210, 2190), ("serve.admit", 1220, 2150),
+               ("serve.admit.sync", 1250, 2095), ("serve.step", 3010, 3990)]
+
+
+def test_trace_reduction_on_synthetic_events():
+    ev = _events()
     r = trace.reduce(ev, n_window_steps=2)
     assert r["window_s"] == pytest.approx(2200e-9)
     assert r["busy_s"] == pytest.approx((750 - 150 + 2000 - 1300) * 1e-9)
@@ -315,6 +486,43 @@ def test_trace_reduction_on_synthetic_events():
     assert sum(idle.values()) == pytest.approx(r["window_s"] - r["busy_s"])
     assert [n for n, _ in r["device_ops"]] == ["qsq_matmul_masked",
                                                 "qsq_matvec_masked", "fusion"]
+
+
+def test_trace_reduction_lists_the_programs_spans_beside_the_old_keys():
+    """The program's spans change none of the old keys; they are listed in
+    the window, and each idle gap goes to the innermost span of either."""
+    old = trace.reduce(_events(), n_window_steps=2)
+    ev = _events()
+    ev["spans"] = SERVE_SPANS + ev["spans"]
+    r = trace.reduce(ev, n_window_steps=2)
+    program_keys = {"spans", "idle_by_span"}
+    assert {k: v for k, v in r.items() if k not in program_keys} == {
+        k: v for k, v in old.items() if k not in program_keys}
+    assert old["spans"] == [] and old["idle_by_span"] == dict(old["idle_gaps"])
+    assert [s[0] for s in r["spans"]] == [n for n, _, _ in SERVE_SPANS[:-1]]
+    assert r["spans"][0] == ("serve.step", pytest.approx(110e-9),
+                             pytest.approx(1090e-9))
+    assert r["idle_by_span"] == pytest.approx({
+        "bench.submit": 150e-9, "serve.decode.sync": 550e-9,
+        "serve.admit": 200e-9})
+    assert sum(r["idle_by_span"].values()) == pytest.approx(
+        r["window_s"] - r["busy_s"])
+    # one sync per step: (980 - 910) and (980 - 845) ns, median 102.5 ns
+    assert cells.reader("step_host_ms")({"trace": r}) == pytest.approx(102.5e-6)
+
+
+def test_device_ops_are_the_top_ten_of_every_op():
+    ev = _events()
+    ev["ops"] = [(f"%op{i}.{i} = f32[8] fusion(...)", 100 * i, 100 * i + 10 + i)
+                 for i in range(12)]
+    ev["ops"].append(("%while.1 = (s32[]) while(...)", 0, 1500))
+    r = trace.reduce(ev, n_window_steps=2)
+    assert len(r["op_s"]) == 12 and "while" not in r["op_s"]
+    assert r["op_s"]["op0"] == pytest.approx(10e-9)
+    top = sorted(r["op_s"].items(), key=lambda kv: -kv[1])[:trace.TOP]
+    assert r["device_ops"] == [[n, v] for n, v in top]
+    assert sum(r["idle_by_span"].values()) == pytest.approx(
+        r["window_s"] - r["busy_s"])
 
 
 def test_merge_unions_overlapping_intervals():

@@ -7,7 +7,7 @@ from __future__ import annotations
 import time
 
 import pytest
-from bench_chip_helpers import TINY_CONFIG, tiny_root
+from bench_chip_helpers import TINY_CONFIG, add_toy_architecture, tiny_root
 
 from benchmarks.chip import cells, program
 from benchmarks.chip import run as bench_run
@@ -32,6 +32,25 @@ def test_tiny_cell_runs_correct(root, name):
     assert r["checks"]["max_gap"]["value"] <= TINY_CONFIG["check"]["max_gap"]
     assert r["_notes"]["compiled_in_window"] == 0
     assert list(r)[-2:] == ["checks", "_notes"]
+
+
+@pytest.mark.parametrize("name, adapter", [("tiny.closed", "llama"),
+                                           ("toy.closed", "toy")])
+def test_a_traced_cell_runs_correct_through_its_adapter(tmp_path, name, adapter):
+    """A traced tiny run builds the program through the adapter its
+    reference names (the moved llama adapter, or one added as a file),
+    proves correct and reads the engine's own spans."""
+    root = tiny_root(tmp_path)
+    add_toy_architecture(root)
+    here = root / "benchmarks" / "chip"
+    cell = _cell(root, name)
+    assert cells.adapter(cell.config, here).__file__ == str(
+        here / "programs" / f"{adapter}.py")
+    r = bench_run.run_cell(cell, 2**31 + 13, 1.5, True,
+                           t_start=time.perf_counter())
+    assert r["correct"], r["checks"]
+    assert r["_notes"]["compiled_in_window"] == 0
+    assert 0 < r["metrics"]["step_host_ms"]["value"] < 1e3
 
 
 def _alter_decoded(eng):
